@@ -9,6 +9,10 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.compile_cache import use_compile_cache  # noqa: E402
+
+use_compile_cache()
+
 FULL = os.environ.get("REPRO_BENCH_FULL", "0") == "1"
 SMOKE = os.environ.get("REPRO_BENCH_SMOKE", "0") == "1"
 

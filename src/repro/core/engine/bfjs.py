@@ -31,7 +31,7 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from .ops import first_empty_positions
+from .ops import first_empty_positions, server_sum, slot_sum
 from .streams import (INF_SLOT, PolicyResult, SchedStreams, _geometric,
                       make_fault_plane, make_streams, resolve_work_steps)
 
@@ -127,9 +127,10 @@ def run_bfjs_streams(streams: SchedStreams,
     failed probe.
 
     Residuals are maintained incrementally yet exactly: a placement
-    recomputes the target server's residual as ``1 - row.sum()`` over the
-    slot-ordered row, the same expression the reference engine evaluates, so
-    trajectories bit-match (as long as ``truncated`` stays 0).
+    recomputes the target server's residual as ``1 - slot_sum(row)`` over
+    the slot-ordered row, the same expression the reference engine and the
+    kernel evaluate, so trajectories bit-match on every backend (as long as
+    ``truncated`` stays 0).
 
     Streams carrying a fault plane (``streams.up is not None``) run the
     fault-injected variant: down servers evict their jobs (``_preempt_grid``
@@ -182,7 +183,7 @@ def run_bfjs_streams(streams: SchedStreams,
             q_cnt = q_cnt + n_r
             freed = (freed | (up_t & ~up_last)) & up_t
             up_last = up_t
-        resid = 1.0 - srv.sum(axis=1)
+        resid = 1.0 - slot_sum(srv)[:, 0]
 
         # 2. arrivals -> first empty queue slots (record where they landed)
         n_empty = jnp.cumsum((queue == 0.0).astype(jnp.int32))
@@ -263,7 +264,7 @@ def run_bfjs_streams(streams: SchedStreams,
                 qtry = qtry.at[qidx].set(0, mode="drop")
             queue = queue.at[qidx].set(0.0, mode="drop")
             resid = resid.at[jnp.where(do, tgt, L)].set(
-                1.0 - new_row.sum(), mode="drop")
+                1.0 - slot_sum(new_row)[0], mode="drop")
             q_cnt = q_cnt - do.astype(jnp.int32)
             dc = dc + any_bfs.astype(jnp.int32)
             a_ptr = a_ptr + is_bfj.astype(jnp.int32)
@@ -288,7 +289,8 @@ def run_bfjs_streams(streams: SchedStreams,
         pend_bfj = (left & (sz_left > 0) & (sz_left <= cap_max)).any()
         trunc = trunc + (pend_bfs | pend_bfj).astype(jnp.int32)
 
-        out = (q_cnt, srv.sum(), n_dep.astype(jnp.int32))
+        out = (q_cnt, server_sum(slot_sum(srv))[0, 0],
+               n_dep.astype(jnp.int32))
         return (srv, dep, queue, t + 1, q_cnt, dropped, trunc,
                 qtry, tries, preempted, requeued, lost, up_last), out
 
@@ -320,8 +322,8 @@ def run_bfjs_streams(streams: SchedStreams,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("sampler", "L", "K", "Qcap", "A_max", "horizon",
-                     "fault_rate", "repair_rate", "max_requeue"),
+    static_argnames=("lam", "mu", "sampler", "L", "K", "Qcap", "A_max",
+                     "horizon", "fault_rate", "repair_rate", "max_requeue"),
 )
 def _run_bfjs_reference(key: jax.Array,
                         lam: float,
@@ -339,7 +341,8 @@ def _run_bfjs_reference(key: jax.Array,
     """The original nested fori/while/cond slot engine (behavioural oracle).
 
     Serial and branch-heavy — kept verbatim for equivalence testing and as
-    the baseline of benchmarks/sched_micro.py.
+    the baseline of benchmarks/sched_micro.py.  It draws each slot's
+    randomness in-loop from ``key``, on the chain ``make_streams`` replays.
 
     ``fault_rate > 0`` runs the fault-injected variant: the oracle
     regenerates the exact ``make_fault_plane`` the scan engine's streams
@@ -347,22 +350,58 @@ def _run_bfjs_reference(key: jax.Array,
     eviction between departures and arrivals, so faulted trajectories stay
     bit-matched engine-to-engine.
     """
+    def draw(key, _):
+        key, _, k_n, k_sizes, k_dur = jax.random.split(key, 5)
+        return (key, jnp.minimum(jax.random.poisson(k_n, lam), A_max),
+                sampler(k_sizes, A_max),
+                _geometric(k_dur, mu, (L * K + A_max,)))
+
+    xs = {"t": jnp.arange(horizon, dtype=jnp.int32)}
+    if fault_rate > 0.0:
+        xs["up"] = make_fault_plane(key, L=L, horizon=horizon,
+                                    fault_rate=fault_rate,
+                                    repair_rate=repair_rate)
+    return _bfjs_reference(draw, key, xs, L=L, K=K, Qcap=Qcap, A_max=A_max,
+                           max_requeue=max_requeue)
+
+
+def _run_bfjs_reference_streams(streams: SchedStreams, *, L: int, K: int,
+                                Qcap: int, A_max: int,
+                                max_requeue: int = DEFAULT_MAX_REQUEUE
+                                ) -> PolicyResult:
+    """The same oracle on pre-drawn ``make_streams`` streams: slot t reads
+    ``n[t]``, ``sizes[t]``, ``durs[t]`` (and the fault plane) instead of
+    drawing them, so it shares the scan engine's inputs exactly — on a TPU
+    the in-loop draws can round a rare duration differently from the
+    batched ``make_streams`` draws."""
+    _check_sequential_durs(streams, L, K, A_max)
+    xs = {"t": jnp.arange(streams.n.shape[0], dtype=jnp.int32),
+          "n": streams.n, "sizes": streams.sizes, "durs": streams.durs}
+    if streams.up is not None:
+        xs["up"] = streams.up
+    return _bfjs_reference(
+        lambda key, x: (key, x["n"], x["sizes"], x["durs"]),
+        jax.random.PRNGKey(0), xs, L=L, K=K, Qcap=Qcap, A_max=A_max,
+        max_requeue=max_requeue)
+
+
+def _bfjs_reference(draw, key, xs, *, L, K, Qcap, A_max, max_requeue):
+    """The oracle's slot loop.  ``xs`` holds the per-slot inputs (``t``,
+    the fault plane ``up`` when faulted, and whatever ``draw`` reads);
+    ``draw(key, x) -> (key, n, sizes, durs)`` supplies slot x's arrivals."""
     from .ops import best_fit_server, largest_fitting_job
 
-    faulted = fault_rate > 0.0
+    faulted = "up" in xs
 
     def place_in_server(srv_i, dep_i, size, dslot):
         slot = jnp.argmax(srv_i == 0.0)
         return srv_i.at[slot].set(size), dep_i.at[slot].set(dslot), slot
 
-    def slot_step(state: BFJSState, inp):
+    def slot_step(state: BFJSState, x):
         (srv, dep, queue, dropped, key, qtry, tries,
          preempted, requeued, lost, up_last) = state
-        if faulted:
-            t, up_t = inp
-        else:
-            t = inp
-        key, k_arr, k_n, k_sizes, k_dur = jax.random.split(key, 5)
+        t, up_t = x["t"], x.get("up")
+        key, n, sizes, durs = draw(key, x)
 
         # 1. departures
         leaving = dep == t
@@ -385,8 +424,6 @@ def _run_bfjs_reference(key: jax.Array,
             up_last = up_t
 
         # 2. arrivals -> queue (record the slots they landed in)
-        n = jnp.minimum(jax.random.poisson(k_n, lam), A_max)
-        sizes = sampler(k_sizes, A_max)
         valid = jnp.arange(A_max) < n
         empty_slots = jnp.nonzero(queue == 0.0, size=A_max, fill_value=Qcap)[0]
         landed = valid & (empty_slots < Qcap)
@@ -395,7 +432,6 @@ def _run_bfjs_reference(key: jax.Array,
             jnp.where(landed, sizes, 0.0), mode="drop")
         new_pos = jnp.where(landed, empty_slots, -1)
 
-        durs = _geometric(k_dur, mu, (L * K + A_max,))
         dcounter = 0
 
         # 3. BF-S over freed servers: fill each with the largest fitting job.
@@ -404,7 +440,7 @@ def _run_bfjs_reference(key: jax.Array,
 
             def try_place(carry):
                 srv, dep, queue, qtry, tries, dc, go = carry
-                resid = 1.0 - srv[i].sum()
+                resid = 1.0 - slot_sum(srv[i])[0]
                 j = largest_fitting_job(queue, resid)
                 ok = j >= 0
 
@@ -442,7 +478,7 @@ def _run_bfjs_reference(key: jax.Array,
             srv, dep, queue, qtry, tries, dc = carry
             pos = new_pos[a]
             size = jnp.where(pos >= 0, queue[jnp.maximum(pos, 0)], 0.0)
-            resid = 1.0 - srv.sum(axis=1)
+            resid = 1.0 - slot_sum(srv)[:, 0]
             if faulted:
                 resid = jnp.where(up_t, resid, -jnp.inf)
             s_idx = best_fit_server(resid, jnp.where(size > 0, size, jnp.inf))
@@ -466,7 +502,7 @@ def _run_bfjs_reference(key: jax.Array,
 
         out = (
             (queue > 0).sum().astype(jnp.int32),
-            srv.sum(),
+            server_sum(slot_sum(srv))[0, 0],
             n_dep.astype(jnp.int32),
         )
         return BFJSState(srv, dep, queue, dropped, key, qtry, tries,
@@ -486,10 +522,6 @@ def _run_bfjs_reference(key: jax.Array,
         lost=zero,
         up_last=jnp.ones((L,), bool),
     )
-    ts = jnp.arange(horizon, dtype=jnp.int32)
-    xs = (ts, make_fault_plane(key, L=L, horizon=horizon,
-                               fault_rate=fault_rate,
-                               repair_rate=repair_rate)) if faulted else ts
     state, (qlen, occ, ndep) = jax.lax.scan(slot_step, state0, xs)
     return PolicyResult(qlen, occ, jnp.cumsum(ndep), state.dropped,
                         jnp.zeros((), jnp.int32), state.preempted,
@@ -552,20 +584,21 @@ def run_bfjs_trace(streams: SchedStreams, *, L: int, K: int, Qcap: int,
     the horizon; ignored by the other engines)."""
     _check_sequential_durs(streams, L, K, A_max)
     if engine == "reference":
-        raise ValueError(
-            "bfjs has no stream-driven reference engine: its oracle draws "
-            "RNG in-loop from a key.  Use engine=\"scan\"/\"pallas\" on "
-            "streams, or run_bfjs(key, ..., engine=\"reference\").")
+        return _run_bfjs_reference_streams(streams, L=L, K=K, Qcap=Qcap,
+                                           A_max=A_max,
+                                           max_requeue=max_requeue)
     if engine == "scan":
         return run_bfjs_streams(streams, L=L, K=K, Qcap=Qcap, A_max=A_max,
                                 work_steps=work_steps,
                                 max_requeue=max_requeue)
     if engine == "pallas":
-        from repro.kernels.bfjs.ops import bfjs_scratch_bytes, bfjs_simulate
-        from repro.kernels.common import ensemble_plane_bytes, pallas_precheck
+        from repro.kernels.bfjs.ops import bfjs_simulate, bfjs_vmem_bytes
+        from repro.kernels.common import (ensemble_plane_bytes,
+                                          pallas_precheck, resolve_windows)
         T, D = streams.n.shape[0], streams.durs.shape[-1]
         if not pallas_precheck(
-                "bfjs", nbytes=bfjs_scratch_bytes(L, K, Qcap, A_max),
+                "bfjs", nbytes=bfjs_vmem_bytes(
+                    L, K, Qcap, A_max, resolve_windows(T, window)[0]),
                 hbm_bytes=ensemble_plane_bytes(
                     1, T, stream_lanes=1 + A_max + D, out_lanes=3),
                 fault_plane=streams.up is not None, strict=strict):
@@ -615,14 +648,16 @@ def monte_carlo_bfjs(keys: jax.Array, lam: float, mu: float, sampler,
     ensemble as the kernel grid (one independent cluster per program
     instance)."""
     if engine == "pallas":
-        from repro.kernels.bfjs.ops import bfjs_scratch_bytes, bfjs_simulate
-        from repro.kernels.common import ensemble_plane_bytes, pallas_precheck
+        from repro.kernels.bfjs.ops import bfjs_simulate, bfjs_vmem_bytes
+        from repro.kernels.common import (ensemble_plane_bytes,
+                                          pallas_precheck, resolve_windows)
         # keys is the LOCAL batch here: under a sharded mesh launch
         # (core.engine.sharding) each device traces with its G/D shard, so
         # this footprint check is naturally per device.
         G = int(keys.shape[0])
         if not pallas_precheck(
-                "bfjs", nbytes=bfjs_scratch_bytes(L, K, Qcap, A_max),
+                "bfjs", nbytes=bfjs_vmem_bytes(
+                    L, K, Qcap, A_max, resolve_windows(horizon, window)[0]),
                 hbm_bytes=ensemble_plane_bytes(
                     G, horizon, stream_lanes=1 + A_max + (L * K + A_max),
                     out_lanes=3),

@@ -441,13 +441,15 @@ def run_bfjs_mr_trace(streams: SchedStreams, *, L: int, K: int = 16,
         return _run_bfjs_mr_reference(streams, L=L, capacity=capacity,
                                       max_requeue=max_requeue)
     if engine == "pallas":
-        from repro.kernels.bfjs_mr.ops import (bfjs_mr_scratch_bytes,
-                                               bfjs_mr_simulate)
-        from repro.kernels.common import ensemble_plane_bytes, pallas_precheck
+        from repro.kernels.bfjs_mr.ops import (bfjs_mr_simulate,
+                                               bfjs_mr_vmem_bytes)
+        from repro.kernels.common import (ensemble_plane_bytes,
+                                          pallas_precheck, resolve_windows)
         R = int(streams.sizes.shape[-1])
         T, D = streams.n.shape[0], streams.durs.shape[-1]
         if not pallas_precheck(
-                "bfjs-mr", nbytes=bfjs_mr_scratch_bytes(L, K, Qcap, R),
+                "bfjs-mr", nbytes=bfjs_mr_vmem_bytes(
+                    L, K, Qcap, A_max, R, resolve_windows(T, window)[0]),
                 hbm_bytes=ensemble_plane_bytes(
                     1, T, stream_lanes=1 + A_max * R + D, out_lanes=2 + R),
                 fault_plane=streams.up is not None, strict=strict):
@@ -511,15 +513,17 @@ def monte_carlo_bfjs_mr_workload(workload, keys, *, engine: str = "scan",
                                     max_requeue=max_requeue) for k in keys]
         return jax.tree.map(lambda *xs: jnp.stack(xs), *res)
     if engine == "pallas":
-        from repro.kernels.bfjs_mr.ops import (bfjs_mr_scratch_bytes,
-                                               bfjs_mr_simulate)
-        from repro.kernels.common import ensemble_plane_bytes, pallas_precheck
+        from repro.kernels.bfjs_mr.ops import (bfjs_mr_simulate,
+                                               bfjs_mr_vmem_bytes)
+        from repro.kernels.common import (ensemble_plane_bytes,
+                                          pallas_precheck, resolve_windows)
         R = int(workload.num_resources)
         # keys is the LOCAL batch under a sharded mesh launch, so the
         # footprint check is per device (core.engine.sharding).
         G = int(keys.shape[0])
         if not pallas_precheck(
-                "bfjs-mr", nbytes=bfjs_mr_scratch_bytes(L, K, Qcap, R),
+                "bfjs-mr", nbytes=bfjs_mr_vmem_bytes(
+                    L, K, Qcap, A_max, R, resolve_windows(horizon, window)[0]),
                 hbm_bytes=ensemble_plane_bytes(
                     G, horizon,
                     stream_lanes=1 + A_max * R + (L * K + A_max),

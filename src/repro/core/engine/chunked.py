@@ -235,16 +235,15 @@ def run_chunked(streams: SchedStreams, *, policy: str = "bfjs",
             return jax.vmap(lambda x, y: base(x, y, config))(s, st)
 
         if mesh is not None:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
             from .sharding import _check_divides
             _check_divides(int(streams.n.shape[0]), mesh)
             spec = P(mesh.axis_names[0])
             out = (spec, spec)
-            _first = shard_map(_first, mesh=mesh, in_specs=(spec,),
-                               out_specs=out, check_rep=False)
-            _next = shard_map(_next, mesh=mesh, in_specs=(spec, spec),
-                              out_specs=out, check_rep=False)
+            _first = jax.shard_map(_first, mesh=mesh, in_specs=(spec,),
+                                   out_specs=out, check_vma=False)
+            _next = jax.shard_map(_next, mesh=mesh, in_specs=(spec, spec),
+                                  out_specs=out, check_vma=False)
         # jit once per run so every chunk reuses the compilation; the
         # previous chunk's carry is donated — its buffers back the next
         # chunk's state in place.

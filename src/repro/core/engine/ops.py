@@ -40,6 +40,43 @@ def best_fit_place(residuals: jax.Array, sizes: jax.Array) -> tuple[jax.Array, j
     return assign.astype(jnp.int32), new_resid
 
 
+def slot_sum(x: jax.Array) -> jax.Array:
+    """Sum of float job sizes over the last (job-slot) axis, keepdims, in
+    one fixed order: the slots zero-padded to a power of two, then halved
+    pairwise (``x[:h] + x[h:]``) down to one.
+
+    Float addition is not associative, and XLA and the TPU kernel compiler
+    each pick their own tree for ``sum``, so on a TPU the scan engine and
+    the fused kernel disagreed in the last bit — and a residual's last bit
+    decides best-fit ties.  Every BF-J/S engine and the kernel sum in this
+    one written-out order (pure slices and adds, which no compiler
+    reassociates), so residuals agree bit for bit on every backend."""
+    w = x.shape[-1]
+    pad = (1 << (w - 1).bit_length()) - w
+    if pad:
+        x = jnp.concatenate(
+            [x, jnp.zeros(x.shape[:-1] + (pad,), x.dtype)], axis=-1)
+    while x.shape[-1] > 1:
+        h = x.shape[-1] // 2
+        x = x[..., :h] + x[..., h:]
+    return x
+
+
+def server_sum(col: jax.Array) -> jax.Array:
+    """Sum of an ``(L, 1)`` per-server column to ``(1, 1)`` in one fixed
+    order: zero-padded 8-row blocks added in block order, then the 8
+    partial sums pairwise.  The cluster-total counterpart of
+    :func:`slot_sum`, shared by the engines and the kernel."""
+    pad = -col.shape[0] % 8
+    if pad:
+        col = jnp.concatenate([col, jnp.zeros((pad, 1), col.dtype)], axis=0)
+    acc = col[0:8]
+    for b in range(1, col.shape[0] // 8):
+        acc = acc + col[8 * b:8 * b + 8]
+    p = [acc[i:i + 1] for i in range(8)]
+    return ((p[0] + p[1]) + (p[2] + p[3])) + ((p[4] + p[5]) + (p[6] + p[7]))
+
+
 def alignment_score_pair_jnp(avail: jax.Array,
                              demand: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Tetris alignment <demand, avail> per server (paper §VIII), exact.

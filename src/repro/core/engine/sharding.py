@@ -43,7 +43,6 @@ import functools
 
 import jax
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .streams import make_streams
@@ -126,8 +125,8 @@ _RUNNERS: dict = {}
 def _sharded_runner(workload, *, spec, mesh, engine, config):
     axis = mesh.axis_names[0]
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=P(axis),
-                       out_specs=P(axis), check_rep=False)
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=P(axis),
+                       out_specs=P(axis), check_vma=False)
     def run(local_keys):
         return spec.monte_carlo(workload, local_keys, engine=engine,
                                 **config)

@@ -276,16 +276,6 @@ def _chunk_shape(streams: SchedStreams) -> tuple:
     return (ensemble, G, int(streams.sizes.shape[streams.n.ndim]), R)
 
 
-def _is_ready(arr) -> bool:
-    """True when a dispatched array's computation has completed (False =
-    still in flight).  Falls back to True — counting a chunk as
-    device-idle — on runtimes without ``is_ready`` introspection."""
-    try:
-        return bool(arr.is_ready())
-    except AttributeError:
-        return True
-
-
 def stream_policy(chunks: Iterable, *, policy: str = "bfjs",
                   engine: str = "scan",
                   checkpoint_dir: str | None = None,
@@ -616,9 +606,7 @@ def stream_policy(chunks: Iterable, *, policy: str = "bfjs",
             staged, src = pull_staged(src + 1)
         except StopIteration:
             exhausted = True
-        if not _is_ready(ready_leaf):
-            pass  # device still busy: ingestion kept up
-        elif not exhausted:
+        if ready_leaf.is_ready() and not exhausted:
             chunks_behind += 1  # device idle before the host had chunk N+1
         if audit:
             dep_base = audit_zero if partial is None \

@@ -24,10 +24,12 @@ reject trace-shaped streams instead of replaying them wrong.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import numpy as np
 
 INF_SLOT = jnp.iinfo(jnp.int32).max
@@ -124,8 +126,15 @@ BFJSResult = PolicyResult
 
 
 def _geometric(key: jax.Array, mu: float, shape=()) -> jax.Array:
+    """Geometric(mu) service durations, >= 1 slot.  ``mu`` is a Python
+    float: the scale ``1 / log1p(-mu)`` is rounded to f32 once, on the host,
+    and multiplied in.  Dividing by ``log1p(-mu)`` on the device gave
+    different draws for the same key eagerly and inside a jitted program:
+    XLA turns a division by a compile-time constant into a multiply by its
+    reciprocal, off by an ulp, and ``ceil`` sometimes flips on that ulp."""
     u = jax.random.uniform(key, shape, minval=1e-7, maxval=1.0)
-    return jnp.maximum(jnp.ceil(jnp.log(u) / jnp.log1p(-mu)), 1.0).astype(jnp.int32)
+    scale = np.float32(1.0 / math.log1p(-mu))
+    return jnp.maximum(jnp.ceil(jnp.log(u) * scale), 1.0).astype(jnp.int32)
 
 
 #: fold_in tag of the fault-plane key: derived from the stream key but off
@@ -194,7 +203,7 @@ def with_fault_plane(streams: SchedStreams, up) -> SchedStreams:
 
 @functools.partial(
     jax.jit,
-    static_argnames=("sampler", "L", "K", "A_max", "horizon",
+    static_argnames=("lam", "mu", "sampler", "L", "K", "A_max", "horizon",
                      "num_resources", "fault_rate", "repair_rate"))
 def make_streams(key: jax.Array, lam: float, mu: float,
                  sampler: Callable[[jax.Array, int], jax.Array],
@@ -219,6 +228,11 @@ def make_streams(key: jax.Array, lam: float, mu: float,
     plane (``make_fault_plane`` — a two-state Markov chain drawn from a
     fold of the same key), turning the streams into a fault-injected
     cluster simulation; the job streams are bitwise unchanged by it.
+
+    ``lam`` and ``mu`` are static: every caller, eager or inside its own
+    jitted program, compiles the same constants into the draws, so a key
+    gives the same streams everywhere (``ensemble_streams`` outside a
+    Monte-Carlo program reproduces the streams inside it).
     """
 
     def chain(k, _):

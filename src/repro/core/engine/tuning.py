@@ -53,7 +53,7 @@ import numpy as np
 #: Bumping this discards every previously-cached entry (see invalidation
 #: rule above) — bump whenever an engine/kernel change shifts the cost
 #: model under the same shape key.
-SCHEMA = "tuning.v1"
+SCHEMA = "tuning.v2"
 
 _ENV = "REPRO_TUNING_CACHE"
 _DEFAULT_PATH = os.path.join("~", ".cache", "repro", "sched_tuning.json")
@@ -188,7 +188,9 @@ def _default_grids(engine: str, A_max: int, horizon: int):
     windows: list[int | None] = [None]
     if engine == "pallas":
         for div in (2, 4, 8):
-            if horizon % div == 0 and horizon // div >= 8:
+            # a window is the stream blocks' sublane extent: a multiple
+            # of 8 on a TPU (kernels.common.resolve_windows)
+            if horizon % div == 0 and (horizon // div) % 8 == 0:
                 windows.append(horizon // div)
     return ws, windows
 

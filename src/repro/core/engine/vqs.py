@@ -619,11 +619,13 @@ def run_vqs_trace(streams: SchedStreams, *, J: int, L: int, K: int,
                                A_max=A_max, work_steps=work_steps,
                                drain=drain, max_requeue=max_requeue)
     if engine == "pallas":
-        from repro.kernels.common import ensemble_plane_bytes, pallas_precheck
-        from repro.kernels.vqs.ops import vqs_scratch_bytes, vqs_simulate
+        from repro.kernels.common import (ensemble_plane_bytes,
+                                          pallas_precheck, resolve_windows)
+        from repro.kernels.vqs.ops import vqs_simulate, vqs_vmem_bytes
         T, D = streams.n.shape[0], streams.durs.shape[-1]
         if not pallas_precheck(
-                "vqs", nbytes=vqs_scratch_bytes(J, L, K, Qcap),
+                "vqs", nbytes=vqs_vmem_bytes(
+                    J, L, K, Qcap, A_max, resolve_windows(T, window)[0]),
                 hbm_bytes=ensemble_plane_bytes(
                     1, T, stream_lanes=1 + A_max + D, out_lanes=3),
                 fault_plane=streams.up is not None, strict=strict):
@@ -698,13 +700,15 @@ def monte_carlo_vqs(keys: jax.Array, lam: float, mu: float, sampler,
                     strict: bool = False) -> PolicyResult:
     """One simulated cluster per key (vmap; "pallas" uses the kernel grid)."""
     if engine == "pallas":
-        from repro.kernels.common import ensemble_plane_bytes, pallas_precheck
-        from repro.kernels.vqs.ops import vqs_scratch_bytes, vqs_simulate
+        from repro.kernels.common import (ensemble_plane_bytes,
+                                          pallas_precheck, resolve_windows)
+        from repro.kernels.vqs.ops import vqs_simulate, vqs_vmem_bytes
         # keys is the LOCAL batch under a sharded mesh launch, so the
         # footprint check is per device (core.engine.sharding).
         G = int(keys.shape[0])
         if not pallas_precheck(
-                "vqs", nbytes=vqs_scratch_bytes(J, L, K, Qcap),
+                "vqs", nbytes=vqs_vmem_bytes(
+                    J, L, K, Qcap, A_max, resolve_windows(horizon, window)[0]),
                 hbm_bytes=ensemble_plane_bytes(
                     G, horizon, stream_lanes=1 + A_max + (L * K + A_max),
                     out_lanes=3),

@@ -710,12 +710,14 @@ def run_vqs_bf_trace(streams: SchedStreams, *, J: int, L: int, K: int,
                                   A_max=A_max, work_steps=work_steps,
                                   max_requeue=max_requeue)
     if engine == "pallas":
-        from repro.kernels.common import ensemble_plane_bytes, pallas_precheck
-        from repro.kernels.vqs_bf.ops import (vqs_bf_scratch_bytes,
-                                              vqs_bf_simulate)
+        from repro.kernels.common import (ensemble_plane_bytes,
+                                          pallas_precheck, resolve_windows)
+        from repro.kernels.vqs_bf.ops import (vqs_bf_simulate,
+                                              vqs_bf_vmem_bytes)
         T, D = streams.n.shape[0], streams.durs.shape[-1]
         if not pallas_precheck(
-                "vqs_bf", nbytes=vqs_bf_scratch_bytes(J, L, K, Qcap),
+                "vqs_bf", nbytes=vqs_bf_vmem_bytes(
+                    J, L, K, Qcap, A_max, resolve_windows(T, window)[0]),
                 hbm_bytes=ensemble_plane_bytes(
                     1, T, stream_lanes=1 + A_max + D, out_lanes=3),
                 fault_plane=streams.up is not None, strict=strict):
@@ -786,14 +788,16 @@ def monte_carlo_vqs_bf(keys: jax.Array, lam: float, mu: float, sampler,
                        strict: bool = False) -> PolicyResult:
     """One simulated cluster per key (vmap; "pallas" uses the kernel grid)."""
     if engine == "pallas":
-        from repro.kernels.common import ensemble_plane_bytes, pallas_precheck
-        from repro.kernels.vqs_bf.ops import (vqs_bf_scratch_bytes,
-                                              vqs_bf_simulate)
+        from repro.kernels.common import (ensemble_plane_bytes,
+                                          pallas_precheck, resolve_windows)
+        from repro.kernels.vqs_bf.ops import (vqs_bf_simulate,
+                                              vqs_bf_vmem_bytes)
         # keys is the LOCAL batch under a sharded mesh launch, so the
         # footprint check is per device (core.engine.sharding).
         G = int(keys.shape[0])
         if not pallas_precheck(
-                "vqs_bf", nbytes=vqs_bf_scratch_bytes(J, L, K, Qcap),
+                "vqs_bf", nbytes=vqs_bf_vmem_bytes(
+                    J, L, K, Qcap, A_max, resolve_windows(horizon, window)[0]),
                 hbm_bytes=ensemble_plane_bytes(
                     G, horizon, stream_lanes=1 + A_max + (L * K + A_max),
                     out_lanes=3),

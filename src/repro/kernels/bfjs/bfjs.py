@@ -28,10 +28,27 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import resolve_windows
+from repro.core.engine.ops import server_sum, slot_sum
+from repro.kernels.common import (LANES, compiler_params, counter_spec,
+                                  prefix_sum, resolve_windows, slot_out_shape,
+                                  slot_spec, stream_block_bytes, tile_bytes,
+                                  to_windows)
 
 INF_SLOT = jnp.iinfo(jnp.int32).max
 BIG = 3.4e38  # ~f32 max; infeasibility sentinel (matches kernels/best_fit)
+
+
+def bfjs_vmem_bytes(L: int, K: int, Qcap: int, A_max: int, TW: int) -> int:
+    """VMEM the fused BF-J/S kernel takes on the chip: the scratch state
+    (srv and dep (L,K), queue (1,Qcap)), the double-buffered (TW, A_max)
+    size and (TW, L*K+A_max) duration blocks, and the compiler's spills —
+    six (L,128) planes (per-server residual and masks) and two (Qcap,128)
+    planes of the saturation check's transposed queue gather.  All padded
+    to (8,128) tiles.  The spill counts are fitted to the v5e compiler's
+    allocation and kept honest by tests/test_tpu_compile.py."""
+    return (2 * tile_bytes(L, K) + tile_bytes(1, Qcap)
+            + stream_block_bytes(TW, A_max, L * K + A_max)
+            + 6 * tile_bytes(L, LANES) + 2 * tile_bytes(Qcap, LANES))
 
 
 def _bfjs_kernel(n_ref, sizes_ref, durs_ref,
@@ -46,7 +63,8 @@ def _bfjs_kernel(n_ref, sizes_ref, durs_ref,
         srv_ref[...] = jnp.zeros((L, K), jnp.float32)
         dep_ref[...] = jnp.full((L, K), INF_SLOT, jnp.int32)
         queue_ref[...] = jnp.zeros((1, Qcap), jnp.float32)
-        acc_ref[...] = jnp.zeros((1, 4), jnp.int32)
+        for i in range(3):
+            acc_ref[i] = 0
 
     l_iota = jax.lax.broadcasted_iota(jnp.int32, (L, 1), 0)
     k_iota = jax.lax.broadcasted_iota(jnp.int32, (L, K), 1)
@@ -88,13 +106,13 @@ def _bfjs_kernel(n_ref, sizes_ref, durs_ref,
         landed = new_pos >= 0                                # (1, A_max)
         n_landed = landed.sum()
         # landed arrival indices, compacted ascending, + their positions
-        rank = jnp.cumsum(landed.astype(jnp.int32), axis=1) - 1
+        rank = prefix_sum(landed) - 1
         comp = landed & (rank == r_iota)                     # (A, A)
         landed_list = jnp.min(jnp.where(comp, a_iota, A_max - 1),
                               axis=1)[None, :]               # (1, A_max)
         pos_list = jnp.max(jnp.where(comp, new_pos, -1), axis=1)[None, :]
 
-        durs_t = durs_ref[0, tt][None, :]                    # (1, D)
+        durs_t = durs_ref[0, pl.ds(tt, 1), :]                # (1, D)
 
         # 3+4. BF-S then BF-J as one bounded placement work list: each step
         # does the BF-S placement for the lowest-index freed server that
@@ -103,7 +121,7 @@ def _bfjs_kernel(n_ref, sizes_ref, durs_ref,
             dc, a_ptr, n_placed = wcarry
             srv = srv_ref[...]
             queue = queue_ref[...]
-            resid = 1.0 - jnp.sum(srv, axis=1, keepdims=True)  # (L, 1)
+            resid = 1.0 - slot_sum(srv)                        # (L, 1)
             occupied = queue > 0.0
             qmin = jnp.min(jnp.where(occupied, queue, BIG))
             fits = freed & (resid >= qmin) & (qmin < BIG)
@@ -160,7 +178,7 @@ def _bfjs_kernel(n_ref, sizes_ref, durs_ref,
         # the reference engine would still make => divergence this slot.
         srv = srv_ref[...]
         queue = queue_ref[...]
-        resid = 1.0 - jnp.sum(srv, axis=1, keepdims=True)
+        resid = 1.0 - slot_sum(srv)
         qmin = jnp.min(jnp.where(queue > 0.0, queue, BIG))
         pend_bfs = (freed & (resid >= qmin) & (qmin < BIG)).any()
         left = (a_iota >= a_ptr) & (a_iota < n_landed)
@@ -172,15 +190,15 @@ def _bfjs_kernel(n_ref, sizes_ref, durs_ref,
         trunc = trunc + (pend_bfs | pend_bfj).astype(jnp.int32)
 
         qlen_ref[0, tt] = q_cnt
-        occ_ref[0, tt] = jnp.sum(srv)
+        occ_ref[0, tt] = server_sum(slot_sum(srv))[0, 0]
         ndep_ref[0, tt] = n_dep.astype(jnp.int32)
         return q_cnt, dropped, trunc
 
-    acc = acc_ref[...]
-    q_cnt, dropped, trunc = jax.lax.fori_loop(
-        0, TW, slot_step, (acc[0, 0], acc[0, 1], acc[0, 2]))
-    acc_ref[...] = jnp.stack(
-        [q_cnt, dropped, trunc, jnp.int32(0)])[None, :]
+    carry = jax.lax.fori_loop(
+        0, TW, slot_step, (acc_ref[0], acc_ref[1], acc_ref[2]))
+    for i, v in enumerate(carry):
+        acc_ref[i] = v
+    q_cnt, dropped, trunc = carry
     dropped_ref[0, 0] = dropped
     trunc_ref[0, 0] = trunc
 
@@ -213,23 +231,23 @@ def bfjs_pallas(n: jax.Array, sizes: jax.Array, durs: jax.Array,
     qlen, occ, ndep, dropped, trunc = pl.pallas_call(
         kernel,
         grid=(G, NW),
-        out_shape=(jax.ShapeDtypeStruct((G, T), jnp.int32),
-                   jax.ShapeDtypeStruct((G, T), jnp.float32),
-                   jax.ShapeDtypeStruct((G, T), jnp.int32),
-                   jax.ShapeDtypeStruct((G, 1), jnp.int32),
-                   jax.ShapeDtypeStruct((G, 1), jnp.int32)),
-        in_specs=[pl.BlockSpec((1, TW), lambda g, w: (g, w)),
+        out_shape=(slot_out_shape(G, T, TW, jnp.int32),
+                   slot_out_shape(G, T, TW, jnp.float32),
+                   slot_out_shape(G, T, TW, jnp.int32),
+                   jax.ShapeDtypeStruct((G, 1, 1), jnp.int32),
+                   jax.ShapeDtypeStruct((G, 1, 1), jnp.int32)),
+        in_specs=[slot_spec(TW),
                   pl.BlockSpec((1, TW, A_max), lambda g, w: (g, w, 0)),
                   pl.BlockSpec((1, TW, D), lambda g, w: (g, w, 0))],
-        out_specs=(pl.BlockSpec((1, TW), lambda g, w: (g, w)),
-                   pl.BlockSpec((1, TW), lambda g, w: (g, w)),
-                   pl.BlockSpec((1, TW), lambda g, w: (g, w)),
-                   pl.BlockSpec((1, 1), lambda g, w: (g, 0)),
-                   pl.BlockSpec((1, 1), lambda g, w: (g, 0))),
+        out_specs=(slot_spec(TW), slot_spec(TW), slot_spec(TW),
+                   counter_spec(), counter_spec()),
         scratch_shapes=[pltpu.VMEM((L, K), jnp.float32),
                         pltpu.VMEM((L, K), jnp.int32),
                         pltpu.VMEM((1, Qcap), jnp.float32),
-                        pltpu.VMEM((1, 4), jnp.int32)],
+                        pltpu.SMEM((3,), jnp.int32)],
+        compiler_params=compiler_params(
+            bfjs_vmem_bytes(L, K, Qcap, A_max, TW)),
         interpret=interpret,
-    )(n, sizes, durs)
-    return qlen, occ, ndep, dropped[:, 0], trunc[:, 0]
+    )(to_windows(n, TW), sizes, durs)
+    return (qlen.reshape(G, T), occ.reshape(G, T), ndep.reshape(G, T),
+            dropped[:, 0, 0], trunc[:, 0, 0])

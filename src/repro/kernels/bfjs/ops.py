@@ -7,18 +7,8 @@ from repro.core.engine.streams import PolicyResult, SchedStreams, \
     resolve_work_steps
 from repro.kernels.common import interpret_default
 
-from .bfjs import bfjs_pallas
+from .bfjs import bfjs_pallas, bfjs_vmem_bytes  # noqa: F401
 from .ref import bfjs_ref
-
-
-def bfjs_scratch_bytes(L: int, K: int, Qcap: int, A_max: int) -> int:
-    """Estimated per-core VMEM scratch of the fused BF-J/S kernel: the
-    persistent simulation state — srv (L,K) f32, dep (L,K) i32, queue
-    (1,Qcap) f32, scalar block (1,4) i32 — all 4-byte lanes.  Checked
-    against ``kernels.common.vmem_budget_bytes`` by the engine dispatch
-    before launching (graceful-degradation rule, DESIGN.md §8/§9)."""
-    del A_max
-    return 4 * (2 * L * K + Qcap + 4)
 
 
 def bfjs_simulate(streams: SchedStreams, L: int, K: int, Qcap: int,

@@ -16,7 +16,7 @@ The placement logic transcribes the bounded early-exit work list of
 ``repro.core.engine.bfjs_mr.run_bfjs_mr_streams`` with broadcasted-iota
 masks and reductions in place of every dynamic index, and the resource
 axis STATICALLY UNROLLED: vector state is stored as R stacked 2D planes
-(demands ``(L, R*K)`` — plane r in columns ``[r*K, (r+1)*K)`` — and queue
+(demands ``(R, L, K)``, one ``(L, K)`` plane per resource, and queue
 demands ``(R, Qcap)``), so every per-resource feasibility comparison is a
 plain 2D vector op.  The Tetris alignment score is exact integer
 arithmetic compared as a normalized int32 ``(hi, lo)`` pair — the same
@@ -37,27 +37,48 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.quantize import RES
-from repro.kernels.common import resolve_windows
+from repro.kernels.common import (LANES, compiler_params, counter_spec,
+                                  prefix_sum, resolve_windows, slot_out_shape,
+                                  slot_spec, stream_block_bytes, tile_bytes,
+                                  to_windows)
 
 INF_SLOT = jnp.iinfo(jnp.int32).max
 INT32_MAX = jnp.iinfo(jnp.int32).max
 
 
+def bfjs_mr_vmem_bytes(L: int, K: int, Qcap: int, A_max: int, R: int,
+                       TW: int) -> int:
+    """VMEM the fused multi-resource BF-J/S kernel takes on the chip: the
+    scratch state (demand (R,L,K), dep (L,K), occupancy (R,L,1), queue
+    demand (R,Qcap), queue meta (2,Qcap)), the double-buffered
+    (TW, A_max*R) size and (TW, A_max) duration blocks, and the compiler's
+    spills — 12 (L,128) planes and three (A_max,Qcap) planes of the
+    arrival-to-queue gathers.  All padded to (8,128) tiles.  The spill
+    counts are fitted (at R=2) to the v5e compiler's allocation and kept
+    honest by tests/test_tpu_compile.py."""
+    return ((R + 1) * tile_bytes(L, K) + R * tile_bytes(L, 1)
+            + tile_bytes(R, Qcap) + tile_bytes(2, Qcap)
+            + stream_block_bytes(TW, A_max * R, A_max)
+            + 12 * tile_bytes(L, LANES) + 3 * tile_bytes(A_max, Qcap))
+
+
 def _bfjs_mr_kernel(n_ref, sizes_ref, durs_ref,
                     qlen_ref, occ_out_ref, ndep_ref, dropped_ref, trunc_ref,
                     dem_ref, dep_ref, occ_ref, qdem_ref, qmeta_ref, acc_ref,
-                    *, L, K, R, Qcap, A_max, W, TW, CAP, D, EARLY_EXIT):
+                    *, L, K, R, Qcap, A_max, W, TW, CAP, EARLY_EXIT):
     w = pl.program_id(1)
 
     @pl.when(w == 0)
     def _init():
-        dem_ref[...] = jnp.zeros((L, R * K), jnp.int32)
+        dem_ref[...] = jnp.zeros((R, L, K), jnp.int32)
         dep_ref[...] = jnp.full((L, K), INF_SLOT, jnp.int32)
-        occ_ref[...] = jnp.zeros((L, R), jnp.int32)
+        occ_ref[...] = jnp.zeros((R, L, 1), jnp.int32)
         qdem_ref[...] = jnp.zeros((R, Qcap), jnp.int32)
-        meta = jnp.ones((2, Qcap), jnp.int32)       # row 0: qdur (init 1)
-        qmeta_ref[...] = meta.at[1].set(-1)         # row 1: qseq (init -1)
-        acc_ref[...] = jnp.zeros((1, 4), jnp.int32)
+        row = jax.lax.broadcasted_iota(jnp.int32, (2, Qcap), 0)
+        # row 0: qdur (init 1), row 1: qseq (init -1)
+        qmeta_ref[...] = jnp.where(row == 0, 1, -1)
+        for i in range(4):
+            acc_ref[i] = 0
 
     l_col = jax.lax.broadcasted_iota(jnp.int32, (L, 1), 0)
     k_row = jax.lax.broadcasted_iota(jnp.int32, (1, K), 1)
@@ -65,6 +86,20 @@ def _bfjs_mr_kernel(n_ref, sizes_ref, durs_ref,
     a_row = jax.lax.broadcasted_iota(jnp.int32, (1, A_max), 1)
     aa = jax.lax.broadcasted_iota(jnp.int32, (A_max, A_max), 0)
     aq = jax.lax.broadcasted_iota(jnp.int32, (A_max, Qcap), 1)
+    CH = next((c for c in (512, 256, 128) if Qcap % c == 0), Qcap)
+
+    def has_fit(elig, avail):
+        """(L, 1): eligible servers with room for some queued job, i.e.
+        the row-any of the (L, Qcap) fit plane, built CH queue lanes at a
+        time so the plane never lives in VMEM whole."""
+        def chunk(c, acc):
+            off = pl.multiple_of(c * CH, CH)
+            fits = elig & (qmeta_ref[1:2, pl.ds(off, CH)] >= 0)
+            for r in range(R):
+                fits = fits & (qdem_ref[r:r + 1, pl.ds(off, CH)] <= avail[r])
+            return acc | fits.any(axis=1, keepdims=True).astype(jnp.int32)
+        return jax.lax.fori_loop(0, Qcap // CH, chunk,
+                                 jnp.zeros((L, 1), jnp.int32)) != 0
 
     def slot_step(tt, carry):
         q_cnt, seq0, dropped, trunc = carry
@@ -72,17 +107,14 @@ def _bfjs_mr_kernel(n_ref, sizes_ref, durs_ref,
 
         # 1. departures free their demand vectors
         dep = dep_ref[...]
-        dem = dem_ref[...]
-        occ = occ_ref[...]
         leaving = dep == t                                   # (L, K)
         freed = leaving.any(axis=1, keepdims=True)           # (L, 1)
         n_dep = leaving.sum()
-        occ = occ - jnp.concatenate(
-            [jnp.sum(jnp.where(leaving, dem[:, r * K:(r + 1) * K], 0),
-                     axis=1, keepdims=True) for r in range(R)], axis=1)
-        dem = jnp.where(jnp.concatenate([leaving] * R, axis=1), 0, dem)
-        dem_ref[...] = dem
-        occ_ref[...] = occ
+        for r in range(R):
+            dem_r = dem_ref[r]
+            occ_ref[r] = occ_ref[r] - jnp.sum(jnp.where(leaving, dem_r, 0),
+                                              axis=1, keepdims=True)
+            dem_ref[r] = jnp.where(leaving, 0, dem_r)
         dep_ref[...] = jnp.where(leaving, INF_SLOT, dep)
 
         # 2. arrivals -> first empty queue positions (sequential masked
@@ -104,7 +136,7 @@ def _bfjs_mr_kernel(n_ref, sizes_ref, durs_ref,
                     jnp.round(sizes_ref[0, tt, a * R + r] * RES),
                     1.0).astype(jnp.int32), qdem[r:r + 1])
                  for r in range(R)], axis=0)
-            qdur = jnp.where(wm, durs_ref[0, tt, D - A_max + a], qdur)
+            qdur = jnp.where(wm, durs_ref[0, tt, a], qdur)
             qseq = jnp.where(wm, seq0 + a, qseq)
             new_pos = jnp.where(land & (a_row == a), first, new_pos)
             dropped = dropped + jnp.where(valid & ~land, 1, 0)
@@ -115,7 +147,7 @@ def _bfjs_mr_kernel(n_ref, sizes_ref, durs_ref,
         landed = new_pos >= 0                                # (1, A_max)
         n_landed = landed.sum()
         # landed arrival indices, compacted ascending, + their positions
-        rank = jnp.cumsum(landed.astype(jnp.int32), axis=1) - 1
+        rank = prefix_sum(landed) - 1
         comp = landed & (rank == aa)                         # (A, A)
         pos_list = jnp.max(jnp.where(comp, new_pos, -1),
                            axis=1)[None, :]                  # (1, A_max)
@@ -127,23 +159,23 @@ def _bfjs_mr_kernel(n_ref, sizes_ref, durs_ref,
         # the min-alignment feasible server.
         def work(wcarry):
             step, a_ptr, blocked, q_cnt, trunc, _ = wcarry
-            dem = dem_ref[...]
+            # the (L, 1) mask rides the loop as int32: Mosaic cannot carry
+            # bool vectors across loop iterations
+            blocked = blocked != 0
             dep = dep_ref[...]
-            occ = occ_ref[...]
             qdem = qdem_ref[...]
             qmeta = qmeta_ref[...]
             qdur, qseq = qmeta[0:1], qmeta[1:2]
-            avail = [CAP[r] - occ[:, r:r + 1] for r in range(R)]  # (L, 1)
+            avail = [CAP[r] - occ_ref[r] for r in range(R)]  # (L, 1)
 
-            # BF-S candidate
-            fits = (freed & ~blocked) & (qseq >= 0)          # (L, Qcap)
-            for r in range(R):
-                fits = fits & (qdem[r:r + 1] <= avail[r])
-            has_fit = fits.any(axis=1, keepdims=True)
-            cur = jnp.min(jnp.where(has_fit, l_col, L))
+            # BF-S candidate: the fit row of server `cur`
+            cur = jnp.min(jnp.where(has_fit(freed & ~blocked, avail), l_col,
+                                    L))
             any_bfs = cur < L
-            fit_cur = ((l_col == cur) & fits).any(axis=0,
-                                                  keepdims=True)  # (1, Qcap)
+            fit_cur = any_bfs & (qseq >= 0)                  # (1, Qcap)
+            for r in range(R):
+                avail_cur = jnp.sum(jnp.where(l_col == cur, avail[r], 0))
+                fit_cur = fit_cur & (qdem[r:r + 1] <= avail_cur)
             tot = jnp.zeros((1, Qcap), jnp.int32)
             for r in range(R):
                 tot = tot + qdem[r:r + 1]
@@ -207,14 +239,11 @@ def _bfjs_mr_kernel(n_ref, sizes_ref, durs_ref,
             place = do & ok_slot
             wm = (l_col == tgt) & (k_row == jnp.minimum(slot, K - 1)) \
                 & place                                      # (L, K)
-            dem_ref[...] = jnp.concatenate(
-                [jnp.where(wm, d_place[r], dem[:, r * K:(r + 1) * K])
-                 for r in range(R)], axis=1)
+            for r in range(R):
+                dem_ref[r] = jnp.where(wm, d_place[r], dem_ref[r])
+                occ_ref[r] = occ_ref[r] + jnp.where((l_col == tgt) & place,
+                                                    d_place[r], 0)
             dep_ref[...] = jnp.where(wm, t + dur, dep)
-            add_vec = jnp.concatenate(
-                [d.reshape(1, 1) for d in d_place], axis=1)  # (1, R)
-            occ_ref[...] = occ + jnp.where((l_col == tgt) & place,
-                                           add_vec, 0)
             clr = (q_row == qidx) & place
             qdem_ref[...] = jnp.concatenate(
                 [jnp.where(clr, 0, qdem[r:r + 1]) for r in range(R)],
@@ -226,9 +255,10 @@ def _bfjs_mr_kernel(n_ref, sizes_ref, durs_ref,
             trunc = trunc + (do & ~ok_slot).astype(jnp.int32)
             blocked = blocked | (any_bfs & ~ok_slot)
             a_ptr = a_ptr + is_bfj.astype(jnp.int32)
-            return step + 1, a_ptr, blocked, q_cnt, trunc, done
+            return (step + 1, a_ptr, blocked.astype(jnp.int32), q_cnt, trunc,
+                    done)
 
-        winit = (jnp.int32(0), jnp.int32(0), jnp.zeros((L, 1), bool),
+        winit = (jnp.int32(0), jnp.int32(0), jnp.zeros((L, 1), jnp.int32),
                  q_cnt, trunc, jnp.bool_(False))
         if EARLY_EXIT:
             # Same body, but stop as soon as a step reports done — the
@@ -242,14 +272,10 @@ def _bfjs_mr_kernel(n_ref, sizes_ref, durs_ref,
 
         # saturation check (same rule as the scan engine): work the oracle
         # would still do => the bounded list diverged this slot.
-        occ = occ_ref[...]
         qdem = qdem_ref[...]
         qseq = qmeta_ref[...][1:2]
-        avail = [CAP[r] - occ[:, r:r + 1] for r in range(R)]
-        fits = (freed & ~blocked) & (qseq >= 0)
-        for r in range(R):
-            fits = fits & (qdem[r:r + 1] <= avail[r])
-        pend_bfs = fits.any()
+        avail = [CAP[r] - occ_ref[r] for r in range(R)]
+        pend_bfs = has_fit(freed & (blocked == 0), avail).any()
         left = (a_row >= a_ptr) & (a_row < n_landed)
         gmask = aq == jnp.maximum(pos_list, 0).T             # (A_max, Qcap)
         seq_at = jnp.sum(jnp.where(gmask, qseq, 0), axis=1)[None, :]
@@ -263,15 +289,16 @@ def _bfjs_mr_kernel(n_ref, sizes_ref, durs_ref,
         trunc = trunc + (pend_bfs | pend_bfj).astype(jnp.int32)
 
         qlen_ref[0, tt] = q_cnt
-        occ_out_ref[0, tt] = occ_ref[...].sum(axis=0).astype(
-            jnp.float32) / RES
+        for r in range(R):
+            occ_out_ref[r, tt] = jnp.sum(occ_ref[r]).astype(jnp.float32) / RES
         ndep_ref[0, tt] = n_dep.astype(jnp.int32)
         return q_cnt, seq0, dropped, trunc
 
-    acc = acc_ref[...]
-    q_cnt, seq0, dropped, trunc = jax.lax.fori_loop(
-        0, TW, slot_step, (acc[0, 0], acc[0, 1], acc[0, 2], acc[0, 3]))
-    acc_ref[...] = jnp.stack([q_cnt, seq0, dropped, trunc])[None, :]
+    carry = jax.lax.fori_loop(
+        0, TW, slot_step, tuple(acc_ref[i] for i in range(4)))
+    for i, v in enumerate(carry):
+        acc_ref[i] = v
+    q_cnt, seq0, dropped, trunc = carry
     dropped_ref[0, 0] = dropped
     trunc_ref[0, 0] = trunc
 
@@ -290,7 +317,8 @@ def bfjs_mr_pallas(n: jax.Array, sizes: jax.Array, durs: jax.Array,
     n (G, T) int32, sizes (G, T, A_max, R) f32, durs (G, T, D) int32 with
     the per-arrival durations in the last A_max lanes (D = A_max for
     streams_from_trace, D = L*K+A_max for make_streams) — one pre-generated
-    stream set per ensemble member.  ``capacity`` is the per-resource
+    stream set per ensemble member (only those lanes are streamed into
+    the kernel).  ``capacity`` is the per-resource
     server capacity tuple (length R).  Returns per-slot (queue_len (G, T),
     occupancy (G, T, R), departures (G, T)) plus (dropped, truncated) of
     shape (G,).
@@ -311,29 +339,32 @@ def bfjs_mr_pallas(n: jax.Array, sizes: jax.Array, durs: jax.Array,
     CAP = tuple(round(c * RES) for c in capacity)
     kernel = functools.partial(
         _bfjs_mr_kernel, L=L, K=K, R=R, Qcap=Qcap, A_max=A_max,
-        W=work_steps, TW=TW, CAP=CAP, D=D, EARLY_EXIT=early_exit)
+        W=work_steps, TW=TW, CAP=CAP, EARLY_EXIT=early_exit)
     qlen, occ, ndep, dropped, trunc = pl.pallas_call(
         kernel,
         grid=(G, NW),
-        out_shape=(jax.ShapeDtypeStruct((G, T), jnp.int32),
-                   jax.ShapeDtypeStruct((G, T, R), jnp.float32),
-                   jax.ShapeDtypeStruct((G, T), jnp.int32),
-                   jax.ShapeDtypeStruct((G, 1), jnp.int32),
-                   jax.ShapeDtypeStruct((G, 1), jnp.int32)),
-        in_specs=[pl.BlockSpec((1, TW), lambda g, w: (g, w)),
+        out_shape=(slot_out_shape(G, T, TW, jnp.int32),
+                   jax.ShapeDtypeStruct((G, NW, R, TW), jnp.float32),
+                   slot_out_shape(G, T, TW, jnp.int32),
+                   jax.ShapeDtypeStruct((G, 1, 1), jnp.int32),
+                   jax.ShapeDtypeStruct((G, 1, 1), jnp.int32)),
+        in_specs=[slot_spec(TW),
                   pl.BlockSpec((1, TW, A_max * R), lambda g, w: (g, w, 0)),
-                  pl.BlockSpec((1, TW, D), lambda g, w: (g, w, 0))],
-        out_specs=(pl.BlockSpec((1, TW), lambda g, w: (g, w)),
-                   pl.BlockSpec((1, TW, R), lambda g, w: (g, w, 0)),
-                   pl.BlockSpec((1, TW), lambda g, w: (g, w)),
-                   pl.BlockSpec((1, 1), lambda g, w: (g, 0)),
-                   pl.BlockSpec((1, 1), lambda g, w: (g, 0))),
-        scratch_shapes=[pltpu.VMEM((L, R * K), jnp.int32),
+                  pl.BlockSpec((1, TW, A_max), lambda g, w: (g, w, 0))],
+        out_specs=(slot_spec(TW),
+                   pl.BlockSpec((None, None, R, TW),
+                                lambda g, w: (g, w, 0, 0),
+                                memory_space=pltpu.SMEM),
+                   slot_spec(TW), counter_spec(), counter_spec()),
+        scratch_shapes=[pltpu.VMEM((R, L, K), jnp.int32),
                         pltpu.VMEM((L, K), jnp.int32),
-                        pltpu.VMEM((L, R), jnp.int32),
+                        pltpu.VMEM((R, L, 1), jnp.int32),
                         pltpu.VMEM((R, Qcap), jnp.int32),
                         pltpu.VMEM((2, Qcap), jnp.int32),
-                        pltpu.VMEM((1, 4), jnp.int32)],
+                        pltpu.SMEM((4,), jnp.int32)],
+        compiler_params=compiler_params(
+            bfjs_mr_vmem_bytes(L, K, Qcap, A_max, R, TW)),
         interpret=interpret,
-    )(n, sizes.reshape(G, T, A_max * R), durs)
-    return qlen, occ, ndep, dropped[:, 0], trunc[:, 0]
+    )(to_windows(n, TW), sizes.reshape(G, T, A_max * R), durs[..., D - A_max:])
+    return (qlen.reshape(G, T), occ.transpose(0, 1, 3, 2).reshape(G, T, R),
+            ndep.reshape(G, T), dropped[:, 0, 0], trunc[:, 0, 0])

@@ -8,18 +8,8 @@ from repro.core.engine.streams import PolicyResult, SchedStreams, \
     resolve_work_steps
 from repro.kernels.common import interpret_default
 
-from .bfjs_mr import bfjs_mr_pallas
+from .bfjs_mr import bfjs_mr_pallas, bfjs_mr_vmem_bytes  # noqa: F401
 from .ref import bfjs_mr_ref
-
-
-def bfjs_mr_scratch_bytes(L: int, K: int, Qcap: int, R: int) -> int:
-    """Estimated per-core VMEM scratch of the fused multi-resource BF-J/S
-    kernel (the DESIGN.md §8 budget formula): demand (L,R·K), dep (L,K),
-    occupancy (L,R), queue demand (R,Qcap), queue meta (2,Qcap) and the
-    (1,4) scalar block — all int32.  Checked against
-    ``kernels.common.vmem_budget_bytes`` by the engine dispatch before
-    launching (DESIGN.md §8/§9)."""
-    return 4 * (2 * L * K * R + L * K + L * R + 3 * Qcap + 4)
 
 
 def _lift_batched_sizes(streams: SchedStreams) -> SchedStreams:

@@ -9,17 +9,7 @@ from repro.core.engine.vqs import _default_drain
 from repro.kernels.common import interpret_default
 
 from .ref import vqs_ref
-from .vqs import vqs_pallas
-
-
-def vqs_scratch_bytes(J: int, L: int, K: int, Qcap: int) -> int:
-    """Estimated per-core VMEM scratch of the fused VQS kernel: three
-    (L,K) planes, two (2J,Qcap) ring planes, (2,2J) ring heads, (4,L)
-    per-server block, (L,2J) placer block and a (1,2) scalar block — all
-    int32.  Checked against ``kernels.common.vmem_budget_bytes`` by the
-    engine dispatch before launching (DESIGN.md §8/§9)."""
-    nvq = 2 * J
-    return 4 * (3 * L * K + 2 * nvq * Qcap + 2 * nvq + 4 * L + L * nvq + 2)
+from .vqs import vqs_pallas, vqs_vmem_bytes  # noqa: F401
 
 
 def vqs_simulate(streams: SchedStreams, J: int, L: int, K: int, Qcap: int,

@@ -32,11 +32,33 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.quantize import RES, TWO_THIRDS
-from repro.kernels.common import resolve_windows
+from repro.core.partition import k_red
+from repro.kernels.common import (LANES, compiler_params, counter_spec,
+                                  prefix_sum, resolve_windows, slot_out_shape,
+                                  slot_spec, stream_block_bytes, tile_bytes,
+                                  to_windows)
 
 INF_SLOT = jnp.iinfo(jnp.int32).max
 CAP = RES
 RESERVE = TWO_THIRDS
+
+
+def vqs_vmem_bytes(J: int, L: int, K: int, Qcap: int, A_max: int,
+                   TW: int) -> int:
+    """VMEM the fused VQS kernel takes on the chip: the scratch state
+    (three (L,K) planes, two (2J,Qcap) ring planes, (2,2J) ring heads,
+    (4,L) per-server block, (L,2J) placer block), the double-buffered
+    (TW, A_max) size and duration blocks and (C, 2J) configuration table,
+    and the compiler's spills — 23 (L,128) planes of the work list's
+    per-server masks and two (2J,Qcap) planes.  All padded to (8,128)
+    tiles.  The spill counts are fitted to the v5e compiler's allocation
+    and kept honest by tests/test_tpu_compile.py."""
+    nvq = 2 * J
+    return (3 * tile_bytes(L, K) + 2 * tile_bytes(nvq, Qcap)
+            + tile_bytes(2, nvq) + tile_bytes(4, L) + tile_bytes(L, nvq)
+            + stream_block_bytes(TW, A_max, A_max)
+            + 2 * tile_bytes(len(k_red(J)), nvq)
+            + 23 * tile_bytes(L, LANES) + 2 * tile_bytes(nvq, Qcap))
 
 
 def _vqs_kernel(n_ref, sizes_ref, durs_ref, confs_ref,
@@ -56,12 +78,12 @@ def _vqs_kernel(n_ref, sizes_ref, durs_ref, confs_ref,
         reff_ref[...] = jnp.zeros((nvq, Qcap), jnp.int32)
         rdur_ref[...] = jnp.ones((nvq, Qcap), jnp.int32)
         hq_ref[...] = jnp.zeros((2, nvq), jnp.int32)
-        cfg = jnp.zeros((4, L), jnp.int32)
-        cfg = cfg.at[1].set(-1)      # cfg_js = -1 (no active configuration)
-        cfg = cfg.at[3].set(1)       # in_empty: all servers start empty
-        cfg_ref[...] = cfg
+        row = jax.lax.broadcasted_iota(jnp.int32, (4, L), 0)
+        # cfg_js = -1 (no active configuration); in_empty: all start empty
+        cfg_ref[...] = jnp.where(row == 1, -1, jnp.where(row == 3, 1, 0))
         want_ref[...] = jnp.zeros((L, nvq), jnp.int32)
-        acc_ref[...] = jnp.zeros((1, 2), jnp.int32)
+        acc_ref[0] = 0
+        acc_ref[1] = 0
 
     l_col = jax.lax.broadcasted_iota(jnp.int32, (L, 1), 0)
     j_row = jax.lax.broadcasted_iota(jnp.int32, (1, nvq), 1)
@@ -117,8 +139,7 @@ def _vqs_kernel(n_ref, sizes_ref, durs_ref, confs_ref,
             pos = jnp.remainder(head_a + cnt_a, Qcap)
             wm = (j_jq == vq_a) & (q_jq == pos) & land         # (nvq, Qcap)
             reff = jnp.where(wm, eff_a, reff)
-            rdur = jnp.where(wm, durs_ref[0, tt, durs_ref.shape[-1]
-                                          - A_max + a], rdur)
+            rdur = jnp.where(wm, durs_ref[0, tt, a], rdur)
             qcnt = qcnt + jnp.where(oh & land, 1, 0)
             dropped = dropped + jnp.where(valid & ~land, 1, 0)
             arrived = arrived | (oh & valid)
@@ -131,15 +152,18 @@ def _vqs_kernel(n_ref, sizes_ref, durs_ref, confs_ref,
         woken = (want & arrived).any(axis=1, keepdims=True)
         want_ref[...] = (want & ~arrived).astype(jnp.int32)
         cfgm = cfg_ref[...]
-        has_cfg0 = (cfgm[2:3] != 0).T                          # (L, 1)
-        in_empty0 = (cfgm[3:4] != 0).T
+        has_cfg0 = (cfgm[2:3].T != 0)                          # (L, 1)
+        in_empty0 = (cfgm[3:4].T != 0)
         visit = freed | woken | (in_empty0 & (qcnt.sum() > 0))
         renew_needed = visit & (empty_now | ~has_cfg0)
 
         # 4. work list: W placement steps + 1 drain pass (fixed unroll —
         # each iteration is the scan engine's masked-select step verbatim)
         def work(_, wcarry):
+            # (L, 1) masks ride the loop as int32: Mosaic cannot carry
+            # bool vectors across loop iterations
             touched, advanced, trunc = wcarry
+            touched, advanced = touched != 0, advanced != 0
             hq = hq_ref[...]
             head, qcnt = hq[0:1], hq[1:2]
             reff = reff_ref[...]
@@ -147,10 +171,10 @@ def _vqs_kernel(n_ref, sizes_ref, durs_ref, confs_ref,
             srv = srv_ref[...]
             vqof = vqof_ref[...]
             cfgm = cfg_ref[...]
-            cfg_k1 = (cfgm[0:1] != 0).T                        # (L, 1)
+            cfg_k1 = (cfgm[0:1].T != 0)                        # (L, 1)
             cfg_js = cfgm[1:2].T
-            has_cfg = (cfgm[2:3] != 0).T
-            in_empty = (cfgm[3:4] != 0).T
+            has_cfg = (cfgm[2:3].T != 0)
+            in_empty = (cfgm[3:4].T != 0)
             want = want_ref[...] != 0
 
             pending = visit & ~advanced
@@ -168,7 +192,8 @@ def _vqs_kernel(n_ref, sizes_ref, durs_ref, confs_ref,
             r_js = jnp.min(jnp.where((row > 0) & (j_row != 1), j_row, nvq))
             r_js = jnp.where(r_js == nvq, -1, r_js)
             ren = renew_needed & ~touched
-            eff_k1 = jnp.where(ren, r_k1, cfg_k1)
+            # bool selects as logic: Mosaic has no select of i1 vectors
+            eff_k1 = (ren & r_k1) | (~ren & cfg_k1)
             eff_js = jnp.where(ren, r_js, cfg_js)              # (L, 1)
 
             occ = srv.sum(axis=1, keepdims=True)
@@ -193,7 +218,7 @@ def _vqs_kernel(n_ref, sizes_ref, durs_ref, confs_ref,
             tch = pending & (l_col <= placer)
             adv = pending & (l_col < placer)
             do_ren = tch & ren
-            new_k1 = jnp.where(do_ren, r_k1, cfg_k1)
+            new_k1 = (do_ren & r_k1) | (~do_ren & cfg_k1)
             new_js = jnp.where(do_ren, r_js, cfg_js)
             new_has = has_cfg | tch
             # first touch only — see engine/vqs.py (stale empty_now mask)
@@ -223,7 +248,7 @@ def _vqs_kernel(n_ref, sizes_ref, durs_ref, confs_ref,
             durs_w = jnp.sum(jnp.where(wsel, rrow_d, 0), axis=1)[None, :]
             in_q = p_row < qcnt_sel
             budget = jnp.max(jnp.where(rowmask, other_cap - other_occ, -1))
-            fit = in_q & (jnp.cumsum(effs_w, axis=1) <= budget)
+            fit = in_q & (prefix_sum(effs_w) <= budget)
             m = jnp.where(do_k1, 1, fit.sum())
             m = jnp.where(any_p, m, 0)
 
@@ -231,8 +256,9 @@ def _vqs_kernel(n_ref, sizes_ref, durs_ref, confs_ref,
                               axis=0)[None, :]                 # (1, K)
             es = row_srv == 0
             free_cnt = es.sum()
-            slotrank = jnp.cumsum(es.astype(jnp.int32), axis=1) - 1
-            sel = es.T & (slotrank.T == p_row) & (p_row < m)   # (K, P)
+            slotrank = prefix_sum(es) - 1
+            sel = (row_srv.T == 0) & (slotrank.T == p_row) \
+                & (p_row < m)                                  # (K, P)
             val_k = jnp.sum(jnp.where(sel, effs_w, 0), axis=1)[None, :]
             dur_k = jnp.sum(jnp.where(sel, durs_w, 0), axis=1)[None, :]
             placed_k = sel.any(axis=1)[None, :]                # (1, K)
@@ -248,13 +274,14 @@ def _vqs_kernel(n_ref, sizes_ref, durs_ref, confs_ref,
                  new_has.astype(jnp.int32).T, new_empty.astype(jnp.int32).T],
                 axis=0)
             trunc = trunc + jnp.maximum(m - free_cnt, 0)       # K-overflow
-            return touched, advanced, trunc
+            return (touched.astype(jnp.int32), advanced.astype(jnp.int32),
+                    trunc)
 
-        false_col = jnp.zeros((L, 1), bool)
+        zero_col = jnp.zeros((L, 1), jnp.int32)
         _, advanced, trunc = jax.lax.fori_loop(
-            0, W + 1, work, (false_col, false_col, trunc))
+            0, W + 1, work, (zero_col, zero_col, trunc))
         # bound hit with servers still unserved: slot finished lazily
-        trunc = trunc + (visit & ~advanced).any().astype(jnp.int32)
+        trunc = trunc + (visit & (advanced == 0)).any().astype(jnp.int32)
 
         qcnt = hq_ref[1:2, :]
         qlen_ref[0, tt] = qcnt.sum()
@@ -262,10 +289,10 @@ def _vqs_kernel(n_ref, sizes_ref, durs_ref, confs_ref,
         ndep_ref[0, tt] = n_dep.astype(jnp.int32)
         return dropped, trunc
 
-    acc = acc_ref[...]
     dropped, trunc = jax.lax.fori_loop(
-        0, TW, slot_step, (acc[0, 0], acc[0, 1]))
-    acc_ref[...] = jnp.stack([dropped, trunc])[None, :]
+        0, TW, slot_step, (acc_ref[0], acc_ref[1]))
+    acc_ref[0] = dropped
+    acc_ref[1] = trunc
     dropped_ref[0, 0] = dropped
     trunc_ref[0, 0] = trunc
 
@@ -283,7 +310,8 @@ def vqs_pallas(n: jax.Array, sizes: jax.Array, durs: jax.Array,
     n (G, T) int32, sizes (G, T, A_max) f32, durs (G, T, D) int32 with the
     per-arrival durations in the last A_max lanes (D = L*K+A_max for
     make_streams, D = A_max for streams_from_trace) — one pre-generated
-    stream set per ensemble member.  Returns per-slot (queue_len,
+    stream set per ensemble member.  Only those last A_max lanes are
+    streamed into the kernel.  Returns per-slot (queue_len,
     occupancy, departures) of shape (G, T) plus (dropped, truncated) of
     shape (G,).
 
@@ -306,20 +334,17 @@ def vqs_pallas(n: jax.Array, sizes: jax.Array, durs: jax.Array,
     qlen, occ, ndep, dropped, trunc = pl.pallas_call(
         kernel,
         grid=(G, NW),
-        out_shape=(jax.ShapeDtypeStruct((G, T), jnp.int32),
-                   jax.ShapeDtypeStruct((G, T), jnp.float32),
-                   jax.ShapeDtypeStruct((G, T), jnp.int32),
-                   jax.ShapeDtypeStruct((G, 1), jnp.int32),
-                   jax.ShapeDtypeStruct((G, 1), jnp.int32)),
-        in_specs=[pl.BlockSpec((1, TW), lambda g, w: (g, w)),
+        out_shape=(slot_out_shape(G, T, TW, jnp.int32),
+                   slot_out_shape(G, T, TW, jnp.float32),
+                   slot_out_shape(G, T, TW, jnp.int32),
+                   jax.ShapeDtypeStruct((G, 1, 1), jnp.int32),
+                   jax.ShapeDtypeStruct((G, 1, 1), jnp.int32)),
+        in_specs=[slot_spec(TW),
                   pl.BlockSpec((1, TW, A_max), lambda g, w: (g, w, 0)),
-                  pl.BlockSpec((1, TW, D), lambda g, w: (g, w, 0)),
+                  pl.BlockSpec((1, TW, A_max), lambda g, w: (g, w, 0)),
                   pl.BlockSpec((C, nvq), lambda g, w: (0, 0))],
-        out_specs=(pl.BlockSpec((1, TW), lambda g, w: (g, w)),
-                   pl.BlockSpec((1, TW), lambda g, w: (g, w)),
-                   pl.BlockSpec((1, TW), lambda g, w: (g, w)),
-                   pl.BlockSpec((1, 1), lambda g, w: (g, 0)),
-                   pl.BlockSpec((1, 1), lambda g, w: (g, 0))),
+        out_specs=(slot_spec(TW), slot_spec(TW), slot_spec(TW),
+                   counter_spec(), counter_spec()),
         scratch_shapes=[pltpu.VMEM((L, K), jnp.int32),
                         pltpu.VMEM((L, K), jnp.int32),
                         pltpu.VMEM((L, K), jnp.int32),
@@ -328,7 +353,10 @@ def vqs_pallas(n: jax.Array, sizes: jax.Array, durs: jax.Array,
                         pltpu.VMEM((2, nvq), jnp.int32),
                         pltpu.VMEM((4, L), jnp.int32),
                         pltpu.VMEM((L, nvq), jnp.int32),
-                        pltpu.VMEM((1, 2), jnp.int32)],
+                        pltpu.SMEM((2,), jnp.int32)],
+        compiler_params=compiler_params(
+            vqs_vmem_bytes(J, L, K, Qcap, A_max, TW)),
         interpret=interpret,
-    )(n, sizes, durs, confs)
-    return qlen, occ, ndep, dropped[:, 0], trunc[:, 0]
+    )(to_windows(n, TW), sizes, durs[..., D - A_max:], confs)
+    return (qlen.reshape(G, T), occ.reshape(G, T), ndep.reshape(G, T),
+            dropped[:, 0, 0], trunc[:, 0, 0])

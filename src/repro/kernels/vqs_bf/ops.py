@@ -8,19 +8,7 @@ from repro.core.engine.streams import PolicyResult, SchedStreams, \
 from repro.kernels.common import interpret_default
 
 from .ref import vqs_bf_ref
-from .vqs_bf import vqs_bf_pallas
-
-
-def vqs_bf_scratch_bytes(J: int, L: int, K: int, Qcap: int) -> int:
-    """Estimated per-core VMEM scratch of the fused VQS-BF kernel: three
-    (L,K) planes, THREE (2J,Qcap) bucket planes (effective size, duration,
-    sequence stamp — one more than VQS, the price of largest-fit-first
-    FIFO tie-breaking), (2,2J) counts block, (5,L) per-server block,
-    (L,2J) subscription block and a (1,2) scalar block — all int32.
-    Checked against ``kernels.common.vmem_budget_bytes`` by the engine
-    dispatch before launching (DESIGN.md §8/§13)."""
-    nvq = 2 * J
-    return 4 * (3 * L * K + 3 * nvq * Qcap + 2 * nvq + 5 * L + L * nvq + 2)
+from .vqs_bf import vqs_bf_pallas, vqs_bf_vmem_bytes  # noqa: F401
 
 
 def vqs_bf_simulate(streams: SchedStreams, J: int, L: int, K: int,

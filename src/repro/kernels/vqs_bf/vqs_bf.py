@@ -16,9 +16,10 @@ and masked reductions in place of every dynamic index ("pop the largest
 job <= residual" is a three-reduction lexicographic argmax over the
 ``(2J, Qcap)`` planes), unrolled to the fixed ``work_steps + 1`` bound (the
 kernel pays the bound; the host scan engine early-exits — same trajectory).
-Each slot closes with the arrival-side BF-J pass: an unrolled ``A_max``
-loop offering every still-queued arrival (identified by its surviving
-sequence stamp) to the tightest feasible server.
+Each slot closes with the arrival-side BF-J pass: a loop over the slot's
+arrivals (their lanes staged in SMEM by the push loop) offering every
+still-queued arrival (identified by its surviving sequence stamp) to the
+tightest feasible server.
 
 Trajectories are bit-compatible with the scan engine (and, through it,
 with the event-driven ``core/vqs_bf.py`` engine on trace streams) whenever
@@ -35,17 +36,42 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.quantize import RES
-from repro.kernels.common import resolve_windows
+from repro.core.partition import k_red
+from repro.kernels.common import (LANES, arrival_spec, compiler_params,
+                                  counter_spec, resolve_windows,
+                                  slot_out_shape, slot_spec, tile_bytes,
+                                  to_windows)
 
 INF_SLOT = jnp.iinfo(jnp.int32).max
 INF32 = jnp.iinfo(jnp.int32).max
 CAP = RES
 
 
+def vqs_bf_vmem_bytes(J: int, L: int, K: int, Qcap: int, A_max: int,
+                      TW: int) -> int:
+    """VMEM the fused VQS-BF kernel takes on the chip: the scratch state
+    (three (L,K) planes, THREE (2J,Qcap) bucket planes — effective size,
+    duration, sequence stamp, one more than VQS for largest-fit-first FIFO
+    tie-breaking — (2,2J) counts, (5,L) per-server block, (L,2J)
+    subscription block), the double-buffered (C, 2J) configuration table,
+    and the compiler's spills — 23 (L,128) planes of the work list's
+    per-server masks and eight (2J,Qcap) planes of the largest-fit pop.
+    All padded to (8,128) tiles.  The per-window streams live in SMEM
+    (``A_max`` and ``TW`` cost no VMEM).  The spill counts are fitted to
+    the v5e compiler's allocation and kept honest by
+    tests/test_tpu_compile.py."""
+    del A_max, TW
+    nvq = 2 * J
+    return (3 * tile_bytes(L, K) + 3 * tile_bytes(nvq, Qcap)
+            + tile_bytes(2, nvq) + tile_bytes(5, L) + tile_bytes(L, nvq)
+            + 2 * tile_bytes(len(k_red(J)), nvq)
+            + 23 * tile_bytes(L, LANES) + 8 * tile_bytes(nvq, Qcap))
+
+
 def _vqs_bf_kernel(n_ref, sizes_ref, durs_ref, confs_ref,
                    qlen_ref, occ_ref, ndep_ref, dropped_ref, trunc_ref,
                    srv_ref, dep_ref, vqof_ref, reff_ref, rdur_ref, rseq_ref,
-                   meta_ref, cfg_ref, want_ref, acc_ref,
+                   meta_ref, cfg_ref, want_ref, acc_ref, lane_ref,
                    *, J, L, K, Qcap, A_max, W, TW):
     w = pl.program_id(1)
     nvq = 2 * J
@@ -60,12 +86,12 @@ def _vqs_bf_kernel(n_ref, sizes_ref, durs_ref, confs_ref,
         rdur_ref[...] = jnp.ones((nvq, Qcap), jnp.int32)
         rseq_ref[...] = jnp.zeros((nvq, Qcap), jnp.int32)
         meta_ref[...] = jnp.zeros((2, nvq), jnp.int32)  # qcnt row, seq_ctr
-        cfg = jnp.zeros((5, L), jnp.int32)
-        cfg = cfg.at[1].set(-1)      # cfg_js = -1 (no active configuration)
-        cfg = cfg.at[4].set(1)       # in_empty: all servers start empty
-        cfg_ref[...] = cfg
+        row = jax.lax.broadcasted_iota(jnp.int32, (5, L), 0)
+        # cfg_js = -1 (no active configuration); in_empty: all start empty
+        cfg_ref[...] = jnp.where(row == 1, -1, jnp.where(row == 4, 1, 0))
         want_ref[...] = jnp.zeros((L, nvq), jnp.int32)
-        acc_ref[...] = jnp.zeros((1, 2), jnp.int32)
+        acc_ref[0] = 0
+        acc_ref[1] = 0
 
     l_col = jax.lax.broadcasted_iota(jnp.int32, (L, 1), 0)
     j_row = jax.lax.broadcasted_iota(jnp.int32, (1, nvq), 1)
@@ -95,18 +121,16 @@ def _vqs_bf_kernel(n_ref, sizes_ref, durs_ref, confs_ref,
         empty_now = (srv > 0).sum(axis=1, keepdims=True) == 0  # (L, 1)
 
         # 2. arrivals: classify on the grid, push to first-empty bucket
-        # slots with fresh sequence stamps (lane order == push order)
+        # slots with fresh sequence stamps (lane order == push order); each
+        # arrival's (vq, pos, seq, eff, dur, landed) row goes to SMEM for
+        # the BF-J pass of step 5.  Lanes past n_t are no-ops, so the loop
+        # stops at n_t.
         n_t = n_ref[0, tt]
         meta = meta_ref[...]
-        qcnt = meta[0:1]                                       # (1, nvq)
         seq_ctr = meta[1, 0]
-        reff = reff_ref[...]
-        rdur = rdur_ref[...]
-        rseq = rseq_ref[...]
-        arrived = jnp.zeros((1, nvq), bool)
-        lanes = []
-        for a in range(A_max):
-            valid = a < n_t
+
+        def push(a, pcarry):
+            qcnt, dropped, arrived = pcarry
             g = jnp.maximum(jnp.round(sizes_ref[0, tt, a] * RES),
                             1.0).astype(jnp.int32)
             m_h = jnp.int32(0)
@@ -117,40 +141,48 @@ def _vqs_bf_kernel(n_ref, sizes_ref, durs_ref, confs_ref,
             vq_a = jnp.where(3 * g > 2 * upper, 2 * m_h, 2 * m_h + 1)
             vq_a = jnp.where(g <= (RES >> J), nvq - 1, vq_a)
             eff_a = jnp.where(vq_a == nvq - 1, jnp.maximum(g, RES >> J), g)
-            dur_a = durs_ref[0, tt, durs_ref.shape[-1] - A_max + a]
+            dur_a = durs_ref[0, tt, a]
             seq_a = seq_ctr + a
+            reff = reff_ref[...]
             emp_row = (j_jq == vq_a) & (reff == 0)             # (nvq, Qcap)
             pos = jnp.min(jnp.where(emp_row, q_jq, Qcap))
-            land = valid & (pos < Qcap)
+            land = pos < Qcap
             wm = (j_jq == vq_a) & (q_jq == pos) & land
-            reff = jnp.where(wm, eff_a, reff)
-            rdur = jnp.where(wm, dur_a, rdur)
-            rseq = jnp.where(wm, seq_a, rseq)
+            reff_ref[...] = jnp.where(wm, eff_a, reff)
+            rdur_ref[...] = jnp.where(wm, dur_a, rdur_ref[...])
+            rseq_ref[...] = jnp.where(wm, seq_a, rseq_ref[...])
+            for i, v in enumerate((vq_a, pos, seq_a, eff_a, dur_a,
+                                   land.astype(jnp.int32))):
+                lane_ref[i, a] = v
             oh = j_row == vq_a                                 # (1, nvq)
-            qcnt = qcnt + jnp.where(oh & land, 1, 0)
-            dropped = dropped + jnp.where(valid & ~land, 1, 0)
-            arrived = arrived | (oh & valid)
-            lanes.append((vq_a, pos, seq_a, eff_a, dur_a, land))
-        reff_ref[...] = reff
-        rdur_ref[...] = rdur
-        rseq_ref[...] = rseq
-        meta = meta.at[0].set(qcnt[0])
-        meta_ref[...] = meta.at[1, 0].set(seq_ctr + A_max)
+            return (qcnt + jnp.where(oh & land, 1, 0),
+                    dropped + jnp.where(land, 0, 1),
+                    arrived | oh.astype(jnp.int32))
+
+        qcnt, dropped, arrived = jax.lax.fori_loop(
+            0, n_t, push,
+            (meta[0:1], dropped, jnp.zeros((1, nvq), jnp.int32)))
+        arrived = arrived != 0
+        meta_ref[0:1, :] = qcnt
+        meta_ref[1:2, :] = jnp.where(j_row == 0, seq_ctr + A_max, meta[1:2])
 
         # 3. visit set
         want = want_ref[...] != 0                              # (L, nvq)
         woken = (want & arrived).any(axis=1, keepdims=True)
         want_ref[...] = (want & ~arrived).astype(jnp.int32)
         cfgm = cfg_ref[...]
-        has_cfg0 = (cfgm[3:4] != 0).T                          # (L, 1)
-        in_empty0 = (cfgm[4:5] != 0).T
+        has_cfg0 = (cfgm[3:4].T != 0)                          # (L, 1)
+        in_empty0 = (cfgm[4:5].T != 0)
         visit = freed | woken | (in_empty0 & (qcnt.sum() > 0))
         renew_needed = visit & (empty_now | ~has_cfg0)
 
         # 4. work list: W+1 one-placement steps (fixed unroll — each
         # iteration is the scan engine's masked-select step verbatim)
         def work(_, wcarry):
+            # (L, 1) masks ride the loop as int32: Mosaic cannot carry
+            # bool vectors across loop iterations
             touched, advanced, trunc = wcarry
+            touched, advanced = touched != 0, advanced != 0
             qcnt = meta_ref[0:1, :]
             reff = reff_ref[...]
             rdur = rdur_ref[...]
@@ -158,11 +190,11 @@ def _vqs_bf_kernel(n_ref, sizes_ref, durs_ref, confs_ref,
             srv = srv_ref[...]
             vqof = vqof_ref[...]
             cfgm = cfg_ref[...]
-            cfg_k1 = (cfgm[0:1] != 0).T                        # (L, 1)
+            cfg_k1 = (cfgm[0:1].T != 0)                        # (L, 1)
             cfg_js = cfgm[1:2].T
             cfg_ks = cfgm[2:3].T
-            has_cfg = (cfgm[3:4] != 0).T
-            in_empty = (cfgm[4:5] != 0).T
+            has_cfg = (cfgm[3:4].T != 0)
+            in_empty = (cfgm[4:5].T != 0)
             want = want_ref[...] != 0
 
             pending = visit & ~advanced
@@ -183,7 +215,8 @@ def _vqs_bf_kernel(n_ref, sizes_ref, durs_ref, confs_ref,
             r_ks = jnp.sum(jnp.where(j_row == jnp.maximum(r_js, 0), row, 0))
             r_ks = jnp.where(r_js >= 0, r_ks, 0)
             ren = renew_needed & ~touched
-            eff_k1 = jnp.where(ren, r_k1, cfg_k1)
+            # bool selects as logic: Mosaic has no select of i1 vectors
+            eff_k1 = (ren & r_k1) | (~ren & cfg_k1)
             eff_js = jnp.where(ren, r_js, cfg_js)              # (L, 1)
             eff_ks = jnp.where(ren, r_ks, cfg_ks)
 
@@ -207,7 +240,7 @@ def _vqs_bf_kernel(n_ref, sizes_ref, durs_ref, confs_ref,
             tch = pending & (l_col <= placer)
             adv = pending & (l_col < placer)
             do_ren = tch & ren
-            new_k1 = jnp.where(do_ren, r_k1, cfg_k1)
+            new_k1 = (do_ren & r_k1) | (~do_ren & cfg_k1)
             new_js = jnp.where(do_ren, r_js, cfg_js)
             new_ks = jnp.where(do_ren, r_ks, cfg_ks)
             new_has = has_cfg | tch
@@ -227,8 +260,8 @@ def _vqs_bf_kernel(n_ref, sizes_ref, durs_ref, confs_ref,
             do1 = (rowmask & k1_can).any()
             doj = ~do1 & (rowmask & js_can).any()
             jsx_s = jnp.maximum(jnp.max(jnp.where(rowmask, eff_js, -1)), 0)
-            rowsel = jnp.where(do1, j_jq == 1,
-                               jnp.where(doj, j_jq == jsx_s, True))
+            rowsel = (do1 & (j_jq == 1)) | (~do1 & doj & (j_jq == jsx_s)) \
+                | (~do1 & ~doj)
             resid_s = jnp.max(jnp.where(rowmask, resid, -1))
             elig = occ_ring & rowsel & (reff <= resid_s)
             best_eff = jnp.max(jnp.where(elig, reff, 0))
@@ -254,31 +287,33 @@ def _vqs_bf_kernel(n_ref, sizes_ref, durs_ref, confs_ref,
             dep_ref[...] = jnp.where(lk, t + dur_p, dep_ref[...])
             vqof_ref[...] = jnp.where(lk, vq_p, vqof)
             reff_ref[...] = jnp.where(pm & do_place, 0, reff)
-            meta = meta_ref[...]
-            meta_ref[...] = meta.at[0].set(
-                (qcnt - jnp.where((j_row == vq_p) & do_place, 1, 0))[0])
+            meta_ref[0:1, :] = qcnt - jnp.where((j_row == vq_p) & do_place, 1,
+                                                0)
             trunc = trunc + (do_place & ~ok).astype(jnp.int32)  # K-overflow
             new_empty = new_empty & ~(rowmask & do_place)
             cfg_ref[...] = jnp.concatenate(
                 [new_k1.astype(jnp.int32).T, new_js.T, new_ks.T,
                  new_has.astype(jnp.int32).T,
                  new_empty.astype(jnp.int32).T], axis=0)
-            return touched, advanced, trunc
+            return (touched.astype(jnp.int32), advanced.astype(jnp.int32),
+                    trunc)
 
-        false_col = jnp.zeros((L, 1), bool)
+        zero_col = jnp.zeros((L, 1), jnp.int32)
         _, advanced, trunc = jax.lax.fori_loop(
-            0, W + 1, work, (false_col, false_col, trunc))
+            0, W + 1, work, (zero_col, zero_col, trunc))
         # bound hit with servers still unserved: slot finished lazily
-        trunc = trunc + (visit & ~advanced).any().astype(jnp.int32)
+        trunc = trunc + (visit & (advanced == 0)).any().astype(jnp.int32)
 
         # 5. arrival-side BF-J pass: each still-queued arrival (sequence
         # stamp survived the serve pass) to the tightest feasible server
-        for vq_a, pos_a, seq_a, eff_a, dur_a, land in lanes:
+        def bfj(a, trunc):
+            vq_a, pos_a, seq_a, eff_a, dur_a, land = (
+                lane_ref[i, a] for i in range(6))
             reff = reff_ref[...]
             rseq = rseq_ref[...]
             srv = srv_ref[...]
             em = (j_jq == vq_a) & (q_jq == pos_a)
-            queued = land & (jnp.sum(jnp.where(em, reff, 0)) > 0) \
+            queued = (land != 0) & (jnp.sum(jnp.where(em, reff, 0)) > 0) \
                 & (jnp.sum(jnp.where(em, rseq, 0)) == seq_a)
             resid = CAP - srv.sum(axis=1, keepdims=True)       # (L, 1)
             candm = resid >= eff_a
@@ -296,23 +331,23 @@ def _vqs_bf_kernel(n_ref, sizes_ref, durs_ref, confs_ref,
             dep_ref[...] = jnp.where(lk, t + dur_a, dep_ref[...])
             vqof_ref[...] = jnp.where(lk, vq_a, vqof_ref[...])
             reff_ref[...] = jnp.where(em & do, 0, reff)
-            meta = meta_ref[...]
-            meta_ref[...] = meta.at[0].set(
-                (meta[0:1] - jnp.where((j_row == vq_a) & do, 1, 0))[0])
-            trunc = trunc + (do & ~ok).astype(jnp.int32)
-            cfgm = cfg_ref[...]
-            in_empty = (cfgm[4:5] != 0).T & ~(rowmask & do)
-            cfg_ref[...] = cfgm.at[4].set(in_empty.astype(jnp.int32).T[0])
+            meta_ref[0:1, :] = meta_ref[0:1, :] \
+                - jnp.where((j_row == vq_a) & do, 1, 0)
+            in_empty = (cfg_ref[4:5, :].T != 0) & ~(rowmask & do)
+            cfg_ref[4:5, :] = in_empty.astype(jnp.int32).T
+            return trunc + (do & ~ok).astype(jnp.int32)
+
+        trunc = jax.lax.fori_loop(0, n_t, bfj, trunc)
 
         qlen_ref[0, tt] = meta_ref[0:1, :].sum()
         occ_ref[0, tt] = srv_ref[...].sum().astype(jnp.float32) / RES
         ndep_ref[0, tt] = n_dep.astype(jnp.int32)
         return dropped, trunc
 
-    acc = acc_ref[...]
     dropped, trunc = jax.lax.fori_loop(
-        0, TW, slot_step, (acc[0, 0], acc[0, 1]))
-    acc_ref[...] = jnp.stack([dropped, trunc])[None, :]
+        0, TW, slot_step, (acc_ref[0], acc_ref[1]))
+    acc_ref[0] = dropped
+    acc_ref[1] = trunc
     dropped_ref[0, 0] = dropped
     trunc_ref[0, 0] = trunc
 
@@ -329,7 +364,8 @@ def vqs_bf_pallas(n: jax.Array, sizes: jax.Array, durs: jax.Array,
 
     n (G, T) int32, sizes (G, T, A_max) f32, durs (G, T, D) int32 with the
     per-arrival durations in the last A_max lanes — one pre-generated
-    stream set per ensemble member.  Returns per-slot (queue_len,
+    stream set per ensemble member (only those lanes are streamed into
+    the kernel).  Returns per-slot (queue_len,
     occupancy, departures) of shape (G, T) plus (dropped, truncated) of
     shape (G,).  ``window`` splits the horizon into VMEM-sized chunks
     exactly as for the VQS kernel (must divide T)."""
@@ -347,20 +383,16 @@ def vqs_bf_pallas(n: jax.Array, sizes: jax.Array, durs: jax.Array,
     qlen, occ, ndep, dropped, trunc = pl.pallas_call(
         kernel,
         grid=(G, NW),
-        out_shape=(jax.ShapeDtypeStruct((G, T), jnp.int32),
-                   jax.ShapeDtypeStruct((G, T), jnp.float32),
-                   jax.ShapeDtypeStruct((G, T), jnp.int32),
-                   jax.ShapeDtypeStruct((G, 1), jnp.int32),
-                   jax.ShapeDtypeStruct((G, 1), jnp.int32)),
-        in_specs=[pl.BlockSpec((1, TW), lambda g, w: (g, w)),
-                  pl.BlockSpec((1, TW, A_max), lambda g, w: (g, w, 0)),
-                  pl.BlockSpec((1, TW, D), lambda g, w: (g, w, 0)),
+        out_shape=(slot_out_shape(G, T, TW, jnp.int32),
+                   slot_out_shape(G, T, TW, jnp.float32),
+                   slot_out_shape(G, T, TW, jnp.int32),
+                   jax.ShapeDtypeStruct((G, 1, 1), jnp.int32),
+                   jax.ShapeDtypeStruct((G, 1, 1), jnp.int32)),
+        in_specs=[slot_spec(TW),
+                  arrival_spec(TW, A_max), arrival_spec(TW, A_max),
                   pl.BlockSpec((C, nvq), lambda g, w: (0, 0))],
-        out_specs=(pl.BlockSpec((1, TW), lambda g, w: (g, w)),
-                   pl.BlockSpec((1, TW), lambda g, w: (g, w)),
-                   pl.BlockSpec((1, TW), lambda g, w: (g, w)),
-                   pl.BlockSpec((1, 1), lambda g, w: (g, 0)),
-                   pl.BlockSpec((1, 1), lambda g, w: (g, 0))),
+        out_specs=(slot_spec(TW), slot_spec(TW), slot_spec(TW),
+                   counter_spec(), counter_spec()),
         scratch_shapes=[pltpu.VMEM((L, K), jnp.int32),
                         pltpu.VMEM((L, K), jnp.int32),
                         pltpu.VMEM((L, K), jnp.int32),
@@ -370,7 +402,11 @@ def vqs_bf_pallas(n: jax.Array, sizes: jax.Array, durs: jax.Array,
                         pltpu.VMEM((2, nvq), jnp.int32),
                         pltpu.VMEM((5, L), jnp.int32),
                         pltpu.VMEM((L, nvq), jnp.int32),
-                        pltpu.VMEM((1, 2), jnp.int32)],
+                        pltpu.SMEM((2,), jnp.int32),
+                        pltpu.SMEM((6, A_max), jnp.int32)],
+        compiler_params=compiler_params(
+            vqs_bf_vmem_bytes(J, L, K, Qcap, A_max, TW)),
         interpret=interpret,
-    )(n, sizes, durs, confs)
-    return qlen, occ, ndep, dropped[:, 0], trunc[:, 0]
+    )(to_windows(n, TW), sizes, durs[..., D - A_max:], confs)
+    return (qlen.reshape(G, T), occ.reshape(G, T), ndep.reshape(G, T),
+            dropped[:, 0, 0], trunc[:, 0, 0])

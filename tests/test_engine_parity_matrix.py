@@ -111,3 +111,33 @@ def test_google50_uncollapsed_trace_parity(engine, google50_streams):
     np.testing.assert_array_equal(np.asarray(res.departed),
                                   np.asarray(ref.departed))
     assert int(res.departed[-1]) > 0
+
+
+# ---------------------------------------------------------------------------
+# stream-driven parity: every policy's reference on the engines' own streams
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("policy", sorted(MATRIX))
+def test_stream_reference_parity(policy):
+    """``run_policy_streams(engine="reference")`` on ``make_streams``
+    output equals the scan and pallas engines on the same streams: the
+    comparison the chip smoke run makes, with no in-loop draw in it."""
+    from repro.core.engine import make_streams
+    wl, cfg = MATRIX[policy]
+    cfg = dict(cfg)
+    horizon = cfg.pop("horizon")
+    st = make_streams(jax.random.PRNGKey(7), wl.lam, wl.mu, wl.sampler,
+                      L=cfg["L"], K=cfg["K"], A_max=cfg["A_max"],
+                      horizon=horizon, num_resources=wl.num_resources)
+    ref_cfg = {k: v for k, v in cfg.items() if k != "work_steps"}
+    if policy == "bfjs-mr":
+        st = jax.device_get(st)
+        ref_cfg["capacity"] = cfg["capacity"] = wl.capacity
+    ref = run_policy_streams(st, policy=policy, engine="reference",
+                             **ref_cfg)
+    assert int(ref.departed[-1]) > 0
+    for engine in ("scan", "pallas"):
+        res = run_policy_streams(st, policy=policy, engine=engine, **cfg)
+        assert int(res.truncated) == 0
+        for f in ("queue_len", "occupancy", "departed", "dropped"):
+            np.testing.assert_array_equal(np.asarray(getattr(res, f)),
+                                          np.asarray(getattr(ref, f)))

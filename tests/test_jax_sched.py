@@ -132,6 +132,22 @@ def test_streams_bitmatch_reference_inloop_draws():
             np.asarray(_geometric(k_dur, mu, (L * K + A_max,))))
 
 
+def test_streams_are_the_same_eager_and_inside_jit():
+    """A key gives the same streams drawn eagerly and inside a jitted
+    program.  At this size (4.8M duration draws) dividing by a
+    device-side ``log1p(-mu)`` flipped a few ``ceil``s between the two."""
+    from repro.core.engine import make_streams
+    sampler = _uniform_sampler(0.1, 0.6)
+    kw = dict(L=1000, K=16, A_max=64, horizon=300)
+    key = jax.random.PRNGKey(0)
+    eager = make_streams(key, 25.0, 0.01, sampler, **kw)
+    jitted = jax.jit(lambda k: make_streams(k, 25.0, 0.01, sampler,
+                                            **kw))(key)
+    for f in ("n", "sizes", "durs"):
+        np.testing.assert_array_equal(np.asarray(getattr(eager, f)),
+                                      np.asarray(getattr(jitted, f)))
+
+
 def test_scan_engine_truncation_is_flagged_not_silent():
     """A too-small work list must be reported via `truncated`, and a
     sufficient one must reproduce the reference exactly."""
