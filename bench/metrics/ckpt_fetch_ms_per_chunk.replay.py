@@ -1,0 +1,10 @@
+"""The checkpoint's device-to-host copy of the carry, in ms per chunk:
+the program's ``repro.ckpt.fetch`` spans in the traced window (profiler
+trace)."""
+from bench import program_spans
+
+
+def read(run):
+    spans = program_spans.of_run(run)
+    return None if spans is None else \
+        spans.span_ms_per_chunk(["repro.ckpt.fetch"])

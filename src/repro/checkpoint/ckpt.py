@@ -72,27 +72,42 @@ def _tree_def(tree):
     return jax.tree_util.tree_structure(tree)
 
 
-def save(directory: str, step: int, state: Any, extra: dict | None = None
-         ) -> str:
-    """Blocking save. `state` is any pytree of arrays."""
+def save(directory: str, step: int, state: Any, extra: dict | None = None,
+         chunk: int | None = None) -> str:
+    """Blocking save. `state` is any pytree of arrays.
+
+    Its three phases are profiler spans: ``repro.ckpt.wait`` (the device
+    finishing ``state``), ``repro.ckpt.fetch`` (the device-to-host copy;
+    stats ``leaves`` and ``bytes``) and ``repro.ckpt.write`` (npz,
+    checksum, manifest, rename; stat ``bytes``, the npz on disk).  Each
+    carries ``chunk``, the stream chunk the state follows, when given."""
+    ids = {} if chunk is None else {"chunk": chunk}
     final = os.path.join(directory, f"step_{step:08d}")
     tmp = final + ".tmp"
     os.makedirs(tmp, exist_ok=True)
-    flat = _flatten(state)
-    np.savez(os.path.join(tmp, "arrays.npz"), **flat)
-    manifest = {
-        "step": step,
-        "time": time.time(),
-        "num_arrays": len(flat),
-        "total_bytes": int(sum(a.nbytes for a in flat.values())),
-        "arrays_sha256": _sha256_file(os.path.join(tmp, "arrays.npz")),
-        "extra": extra or {},
-    }
-    with open(os.path.join(tmp, "manifest.json"), "w") as f:
-        json.dump(manifest, f, indent=1)
-    if os.path.exists(final):
-        shutil.rmtree(final)
-    os.rename(tmp, final)
+    with jax.profiler.TraceAnnotation("repro.ckpt.wait", **ids):
+        jax.block_until_ready(state)
+    with jax.profiler.TraceAnnotation("repro.ckpt.fetch", **ids) as span:
+        flat = _flatten(state)
+        total_bytes = int(sum(a.nbytes for a in flat.values()))
+        span.set_metadata(leaves=len(flat), bytes=total_bytes)
+    with jax.profiler.TraceAnnotation("repro.ckpt.write", **ids) as span:
+        arrays = os.path.join(tmp, "arrays.npz")
+        np.savez(arrays, **flat)
+        span.set_metadata(bytes=os.path.getsize(arrays))
+        manifest = {
+            "step": step,
+            "time": time.time(),
+            "num_arrays": len(flat),
+            "total_bytes": total_bytes,
+            "arrays_sha256": _sha256_file(arrays),
+            "extra": extra or {},
+        }
+        with open(os.path.join(tmp, "manifest.json"), "w") as f:
+            json.dump(manifest, f, indent=1)
+        if os.path.exists(final):
+            shutil.rmtree(final)
+        os.rename(tmp, final)
     return final
 
 

@@ -118,8 +118,8 @@ def _append(partial: PolicyResult | None, res: PolicyResult,
 def _save_step(checkpoint_dir: str, step: int, payload: Any,
                extra: dict) -> None:
     """One chunk-boundary save (factored out so crash tests can intercept
-    the exact boundary)."""
-    ckpt.save(checkpoint_dir, step, payload, extra=extra)
+    the exact boundary); ``step - 1`` is the chunk it follows."""
+    ckpt.save(checkpoint_dir, step, payload, extra=extra, chunk=step - 1)
 
 
 def _load_step(checkpoint_dir: str, step: int

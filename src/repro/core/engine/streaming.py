@@ -26,6 +26,20 @@ chunk size.  What streaming adds over ``run_chunked`` is the *pipeline*:
     the bottleneck; the healthy state for a serving loop).  Both measure
     host/device overlap only: they are excluded from bit-match
     comparisons, and the trajectory never depends on timing.
+    ``host_stall_us`` covers the pipeline's drain only: with
+    ``checkpoint_dir=`` the host mostly waits for the device inside the
+    checkpoint instead, and that wait is the ``repro.ckpt.wait`` span.
+  * **Profiler spans.**  Each phase of a chunk is a
+    ``jax.profiler.TraceAnnotation`` with the chunk's index as its
+    ``chunk`` stat: ``repro.ingest.chunk`` (re-bucketing trace rows into
+    the chunk), ``repro.stream.stage`` (validating and ``device_put``),
+    ``repro.stream.wait`` (the drain, what ``host_stall_us`` counts),
+    ``repro.stream.checkpoint`` (the whole boundary save) and, inside it,
+    ``repro.ckpt.wait`` / ``repro.ckpt.fetch`` / ``repro.ckpt.write``
+    (``repro.checkpoint.ckpt.save``).  They are recorded only while a
+    profiler trace runs, and cost under a microsecond each otherwise.
+    Ingestion and staging count source chunks, the drain and the
+    checkpoint executed chunks; the two differ only after a quarantine.
   * **Bounded-memory trajectories.**  ``trajectory="full"`` concatenates
     per-chunk planes (the default; what the parity tests compare).
     ``trajectory="tail"`` keeps only the newest chunk's planes — with the
@@ -188,6 +202,7 @@ class _TraceChunkSource:
         self._pending: deque = deque()
         self._exhausted = False
         self._inner_failed = False
+        self.returned = 0    # chunks returned so far
 
     def __iter__(self):
         return self
@@ -215,6 +230,13 @@ class _TraceChunkSource:
         return win
 
     def __next__(self) -> SchedStreams:
+        with jax.profiler.TraceAnnotation("repro.ingest.chunk",
+                                          chunk=self.returned):
+            chunk = self._next_window()
+        self.returned += 1
+        return chunk
+
+    def _next_window(self) -> SchedStreams:
         import types
         while not self._pending and not self._exhausted:
             try:
@@ -433,11 +455,13 @@ def stream_policy(chunks: Iterable, *, policy: str = "bfjs",
     def stage(chunk, index: int):
         """``prepare`` — supervised: retried transients, staging
         watchdog."""
-        if sup is None:
-            return prepare(chunk, index)
-        return sup.call("chunk staging",
-                        lambda: prepare(chunk, index),
-                        chunk_index=index, timeout=sup.stage_timeout)
+        with jax.profiler.TraceAnnotation("repro.stream.stage",
+                                          chunk=index):
+            if sup is None:
+                return prepare(chunk, index)
+            return sup.call("chunk staging",
+                            lambda: prepare(chunk, index),
+                            chunk_index=index, timeout=sup.stage_timeout)
 
     def pull_staged(index: int):
         """Pull + stage source chunk ``index``.  Under supervision, a
@@ -578,12 +602,13 @@ def stream_policy(chunks: Iterable, *, policy: str = "bfjs",
 
     def drain_one() -> None:
         ck, leaf = inflight.popleft()
-        if sup is not None and sup.compute_timeout is not None:
-            sup.watch("device compute",
-                      lambda: jax.block_until_ready(leaf),
-                      sup.compute_timeout, chunk_index=ck)
-        else:
-            jax.block_until_ready(leaf)
+        with jax.profiler.TraceAnnotation("repro.stream.wait", chunk=ck):
+            if sup is not None and sup.compute_timeout is not None:
+                sup.watch("device compute",
+                          lambda: jax.block_until_ready(leaf),
+                          sup.compute_timeout, chunk_index=ck)
+            else:
+                jax.block_until_ready(leaf)
 
     while not exhausted:
         if stop_after_chunks is not None and executed >= stop_after_chunks:
@@ -622,15 +647,18 @@ def stream_policy(chunks: Iterable, *, policy: str = "bfjs",
         if checkpoint_dir is not None:
             # ckpt pulls arrays to host — synchronizes, trading pipeline
             # overlap for crash-safety at every boundary
-            payload = {"state": state, "partial": partial}
-            if sup is None:
-                _save_step(checkpoint_dir, i, payload, meta)
-            else:
-                step = i
-                sup.call(
-                    "checkpoint write",
-                    lambda: _save_step(checkpoint_dir, step, payload, meta),
-                    chunk_index=step - 1)
+            with jax.profiler.TraceAnnotation("repro.stream.checkpoint",
+                                              chunk=i - 1):
+                payload = {"state": state, "partial": partial}
+                if sup is None:
+                    _save_step(checkpoint_dir, i, payload, meta)
+                else:
+                    step = i
+                    sup.call(
+                        "checkpoint write",
+                        lambda: _save_step(checkpoint_dir, step, payload,
+                                           meta),
+                        chunk_index=step - 1)
     # drain the tail of the pipeline so a compute watchdog covers the
     # final dispatch too
     while inflight:
