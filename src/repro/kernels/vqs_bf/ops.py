@@ -24,7 +24,7 @@ def vqs_bf_simulate(streams: SchedStreams, J: int, L: int, K: int,
         return vqs_bf_ref(streams.n, streams.sizes, streams.durs, J=J, L=L,
                           K=K, Qcap=Qcap, A_max=A_max,
                           work_steps=work_steps)
-    qlen, occ, ndep, dropped, trunc = vqs_bf_pallas(
+    qlen, occ, ndep, dropped, trunc, _ = vqs_bf_pallas(
         streams.n, streams.sizes, streams.durs, J=J, L=L, K=K, Qcap=Qcap,
         A_max=A_max, work_steps=work_steps, window=window,
         interpret=interpret_default())

@@ -14,8 +14,11 @@ largest-fit pops from the bucketed rings, shared max-weight renewal,
 vectorized advance-past writes — transcribed with broadcasted-iota masks
 and masked reductions in place of every dynamic index ("pop the largest
 job <= residual" is a three-reduction lexicographic argmax over the
-``(2J, Qcap)`` planes), unrolled to the fixed ``work_steps + 1`` bound (the
-kernel pays the bound; the host scan engine early-exits — same trajectory).
+``(2J, Qcap)`` planes), a ``while_loop`` bounded at ``work_steps + 1`` steps
+that stops after the first step with no placer (that step advances every
+pending server, so later steps would be no-ops — the scan engine's exit,
+plus at most one no-op step when nothing was visited).  The ``steps``
+output counts the work steps each member ran over the horizon.
 Each slot closes with the arrival-side BF-J pass: a loop over the slot's
 arrivals (their lanes staged in SMEM by the push loop) offering every
 still-queued arrival (identified by its surviving sequence stamp) to the
@@ -70,6 +73,7 @@ def vqs_bf_vmem_bytes(J: int, L: int, K: int, Qcap: int, A_max: int,
 
 def _vqs_bf_kernel(n_ref, sizes_ref, durs_ref, confs_ref,
                    qlen_ref, occ_ref, ndep_ref, dropped_ref, trunc_ref,
+                   steps_ref,
                    srv_ref, dep_ref, vqof_ref, reff_ref, rdur_ref, rseq_ref,
                    meta_ref, cfg_ref, want_ref, acc_ref, lane_ref,
                    *, J, L, K, Qcap, A_max, W, TW):
@@ -92,6 +96,7 @@ def _vqs_bf_kernel(n_ref, sizes_ref, durs_ref, confs_ref,
         want_ref[...] = jnp.zeros((L, nvq), jnp.int32)
         acc_ref[0] = 0
         acc_ref[1] = 0
+        acc_ref[2] = 0
 
     l_col = jax.lax.broadcasted_iota(jnp.int32, (L, 1), 0)
     j_row = jax.lax.broadcasted_iota(jnp.int32, (1, nvq), 1)
@@ -103,7 +108,7 @@ def _vqs_bf_kernel(n_ref, sizes_ref, durs_ref, confs_ref,
     confs = confs_ref[...]
 
     def slot_step(tt, carry):
-        dropped, trunc = carry
+        dropped, trunc, steps = carry
         t = w * TW + tt
 
         # 1. departures
@@ -176,12 +181,13 @@ def _vqs_bf_kernel(n_ref, sizes_ref, durs_ref, confs_ref,
         visit = freed | woken | (in_empty0 & (qcnt.sum() > 0))
         renew_needed = visit & (empty_now | ~has_cfg0)
 
-        # 4. work list: W+1 one-placement steps (fixed unroll — each
-        # iteration is the scan engine's masked-select step verbatim)
-        def work(_, wcarry):
+        # 4. work list: at most W+1 one-placement steps, each the scan
+        # engine's masked-select step verbatim; a step with no placer
+        # advances every pending server, so the loop stops after it
+        def work(wcarry):
             # (L, 1) masks ride the loop as int32: Mosaic cannot carry
             # bool vectors across loop iterations
-            touched, advanced, trunc = wcarry
+            step, touched, advanced, trunc, _ = wcarry
             touched, advanced = touched != 0, advanced != 0
             qcnt = meta_ref[0:1, :]
             reff = reff_ref[...]
@@ -295,12 +301,14 @@ def _vqs_bf_kernel(n_ref, sizes_ref, durs_ref, confs_ref,
                 [new_k1.astype(jnp.int32).T, new_js.T, new_ks.T,
                  new_has.astype(jnp.int32).T,
                  new_empty.astype(jnp.int32).T], axis=0)
-            return (touched.astype(jnp.int32), advanced.astype(jnp.int32),
-                    trunc)
+            return (step + 1, touched.astype(jnp.int32),
+                    advanced.astype(jnp.int32), trunc, ~any_p)
 
         zero_col = jnp.zeros((L, 1), jnp.int32)
-        _, advanced, trunc = jax.lax.fori_loop(
-            0, W + 1, work, (zero_col, zero_col, trunc))
+        n_steps, _, advanced, trunc, _ = jax.lax.while_loop(
+            lambda c: (c[0] < W + 1) & jnp.logical_not(c[-1]), work,
+            (jnp.int32(0), zero_col, zero_col, trunc, jnp.bool_(False)))
+        steps = steps + n_steps
         # bound hit with servers still unserved: slot finished lazily
         trunc = trunc + (visit & (advanced == 0)).any().astype(jnp.int32)
 
@@ -342,14 +350,16 @@ def _vqs_bf_kernel(n_ref, sizes_ref, durs_ref, confs_ref,
         qlen_ref[0, tt] = meta_ref[0:1, :].sum()
         occ_ref[0, tt] = srv_ref[...].sum().astype(jnp.float32) / RES
         ndep_ref[0, tt] = n_dep.astype(jnp.int32)
-        return dropped, trunc
+        return dropped, trunc, steps
 
-    dropped, trunc = jax.lax.fori_loop(
-        0, TW, slot_step, (acc_ref[0], acc_ref[1]))
+    dropped, trunc, steps = jax.lax.fori_loop(
+        0, TW, slot_step, (acc_ref[0], acc_ref[1], acc_ref[2]))
     acc_ref[0] = dropped
     acc_ref[1] = trunc
+    acc_ref[2] = steps
     dropped_ref[0, 0] = dropped
     trunc_ref[0, 0] = trunc
+    steps_ref[0, 0] = steps
 
 
 @functools.partial(
@@ -365,10 +375,11 @@ def vqs_bf_pallas(n: jax.Array, sizes: jax.Array, durs: jax.Array,
     n (G, T) int32, sizes (G, T, A_max) f32, durs (G, T, D) int32 with the
     per-arrival durations in the last A_max lanes — one pre-generated
     stream set per ensemble member (only those lanes are streamed into
-    the kernel).  Returns per-slot (queue_len,
-    occupancy, departures) of shape (G, T) plus (dropped, truncated) of
-    shape (G,).  ``window`` splits the horizon into VMEM-sized chunks
-    exactly as for the VQS kernel (must divide T)."""
+    the kernel).  Returns per-slot (queue_len, occupancy, departures) of
+    shape (G, T) plus (dropped, truncated, steps) of shape (G,), ``steps``
+    being the work-list steps each member ran over the horizon (at most
+    ``T * (work_steps + 1)``).  ``window`` splits the horizon into
+    VMEM-sized chunks exactly as for the VQS kernel (must divide T)."""
     from repro.core.engine.ops import k_red_jnp
 
     G, T = n.shape
@@ -380,19 +391,20 @@ def vqs_bf_pallas(n: jax.Array, sizes: jax.Array, durs: jax.Array,
     kernel = functools.partial(
         _vqs_bf_kernel, J=J, L=L, K=K, Qcap=Qcap, A_max=A_max,
         W=work_steps, TW=TW)
-    qlen, occ, ndep, dropped, trunc = pl.pallas_call(
+    qlen, occ, ndep, dropped, trunc, steps = pl.pallas_call(
         kernel,
         grid=(G, NW),
         out_shape=(slot_out_shape(G, T, TW, jnp.int32),
                    slot_out_shape(G, T, TW, jnp.float32),
                    slot_out_shape(G, T, TW, jnp.int32),
                    jax.ShapeDtypeStruct((G, 1, 1), jnp.int32),
+                   jax.ShapeDtypeStruct((G, 1, 1), jnp.int32),
                    jax.ShapeDtypeStruct((G, 1, 1), jnp.int32)),
         in_specs=[slot_spec(TW),
                   arrival_spec(TW, A_max), arrival_spec(TW, A_max),
                   pl.BlockSpec((C, nvq), lambda g, w: (0, 0))],
         out_specs=(slot_spec(TW), slot_spec(TW), slot_spec(TW),
-                   counter_spec(), counter_spec()),
+                   counter_spec(), counter_spec(), counter_spec()),
         scratch_shapes=[pltpu.VMEM((L, K), jnp.int32),
                         pltpu.VMEM((L, K), jnp.int32),
                         pltpu.VMEM((L, K), jnp.int32),
@@ -402,11 +414,11 @@ def vqs_bf_pallas(n: jax.Array, sizes: jax.Array, durs: jax.Array,
                         pltpu.VMEM((2, nvq), jnp.int32),
                         pltpu.VMEM((5, L), jnp.int32),
                         pltpu.VMEM((L, nvq), jnp.int32),
-                        pltpu.SMEM((2,), jnp.int32),
+                        pltpu.SMEM((3,), jnp.int32),
                         pltpu.SMEM((6, A_max), jnp.int32)],
         compiler_params=compiler_params(
             vqs_bf_vmem_bytes(J, L, K, Qcap, A_max, TW)),
         interpret=interpret,
     )(to_windows(n, TW), sizes, durs[..., D - A_max:], confs)
     return (qlen.reshape(G, T), occ.reshape(G, T), ndep.reshape(G, T),
-            dropped[:, 0, 0], trunc[:, 0, 0])
+            dropped[:, 0, 0], trunc[:, 0, 0], steps[:, 0, 0])

@@ -19,7 +19,8 @@ from repro.core import VQSBF, load_trace_csv, simulate_trace
 from repro.core.engine import (make_streams, run_policy, run_policy_streams,
                                streams_from_trace, Workload)
 from repro.core.engine.vqs_bf import (_run_vqs_bf_reference_streams,
-                                      run_vqs_bf_streams)
+                                      run_vqs_bf_streams, run_vqs_bf_trace)
+from repro.kernels.vqs_bf.vqs_bf import vqs_bf_pallas
 
 FIXTURE = os.path.join(os.path.dirname(__file__), "data",
                        "google_like_50.csv")
@@ -119,6 +120,49 @@ def test_vqs_bf_truncation_counted_not_silent():
     res = run_vqs_bf_streams(st, J=3, L=8, K=32, Qcap=512, A_max=8,
                              work_steps=1)
     assert int(res.truncated) > 0
+
+
+@pytest.mark.parametrize("work_steps", [1, 2, 4])
+def test_vqs_bf_kernel_matches_scan_under_starved_bound(work_steps):
+    """The kernel's early-exit work list stops at the scan engine's bound
+    (``n_steps <= W``): a starved bound truncates the same slots and
+    leaves the same trajectory on both engines."""
+    st = make_streams(jax.random.PRNGKey(2), 3.0, 0.01,
+                      _uniform_sampler(0.05, 0.3), L=8, K=32, A_max=8,
+                      horizon=300)
+    kw = dict(J=3, L=8, K=32, Qcap=512, A_max=8, work_steps=work_steps)
+    scn = run_vqs_bf_streams(st, **kw)
+    krn = run_vqs_bf_trace(st, engine="pallas", strict=True, **kw)
+    assert int(scn.truncated) > 0
+    for field in ("queue_len", "occupancy", "departed", "dropped",
+                  "truncated"):
+        np.testing.assert_array_equal(np.asarray(getattr(scn, field)),
+                                      np.asarray(getattr(krn, field)))
+
+
+def test_vqs_bf_kernel_step_counter_shows_early_exit():
+    """``steps`` counts the work steps the kernel ran: the same count and
+    trajectory under any bound that never truncates, at least one step
+    a slot, below the bound, and at most two a slot under ``W = 1``."""
+    st = make_streams(jax.random.PRNGKey(1), 1.0, 0.02,
+                      _uniform_sampler(0.05, 0.9), L=6, K=40, A_max=6,
+                      horizon=300)
+    T = int(st.n.shape[0])
+    kw = dict(J=4, L=6, K=40, Qcap=512, A_max=6, interpret=True)
+
+    def run(work_steps):
+        return vqs_bf_pallas(st.n[None], st.sizes[None], st.durs[None],
+                             work_steps=work_steps, **kw)
+
+    out = {w: [np.asarray(x) for x in run(w)] for w in (1, 32, 64)}
+    *traj32, trunc32, steps32 = out[32]
+    *traj64, trunc64, steps64 = out[64]
+    assert int(trunc32[0]) == 0 and int(trunc64[0]) == 0
+    for a, b in zip(traj32, traj64):
+        np.testing.assert_array_equal(a, b)
+    assert int(steps32[0]) == int(steps64[0])
+    assert T <= int(steps32[0]) < T * (32 + 1)
+    assert T <= int(out[1][-1][0]) <= 2 * T
 
 
 # ---------------------------------------------------------------------------
