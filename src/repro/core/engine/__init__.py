@@ -5,8 +5,8 @@ hosts; this package re-expresses the paper's schedulers as fixed-shape,
 branch-free programs that run ON the accelerator:
 
   * ``workload`` — the first-class :class:`Workload` spec (arrival rate,
-    size sampler, service rate, ``num_resources``, per-resource capacity)
-    every entry point dispatches on;
+    size sampler, service rate, ``num_resources``, per-resource or
+    per-server capacity) every entry point dispatches on;
   * ``streams``  — pre-generated randomness (``SchedStreams``), from PRNG
     keys (``make_streams``) or workload traces (``streams_from_trace``),
     with ``(T, A_max, R)`` requirement vectors when R > 1;

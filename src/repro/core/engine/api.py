@@ -17,8 +17,10 @@ configuration (``J`` for VQS, ``work_steps`` bounds, ...) passes through as
 keyword arguments; unknown keys are rejected by the policy's runner.
 
 Multi-resource workloads (``num_resources=R > 1``, per-resource
-``capacity``) route to ``policy="bfjs-mr"`` — the Tetris-alignment BF-J/S
-of paper Section VIII; the single-resource policies reject them loudly.
+``capacity``) and heterogeneous fleets (an ``(L, R)`` per-server
+``capacity`` plane) route to ``policy="bfjs-mr"`` — the Tetris-alignment
+BF-J/S of paper Section VIII; the single-resource policies reject them
+loudly.
 
 The PR 2 loose-argument signatures, ``run_policy(key, lam, mu, sampler,
 ...)`` / ``monte_carlo_policy(keys, lam, mu, sampler, ...)``, remain as

@@ -11,7 +11,8 @@ Multiresource-Job Scheduling*) — onto the fixed-shape accelerator stack as
   * ``engine="scan"``      — a branch-free ``lax.scan`` over slots with a
     bounded early-exit placement work list, the same program shape as the
     single-resource BF-J/S scan engine, generalized to ``(L, R)`` integer
-    occupancy planes and ``(Qcap, R)`` queued demand vectors;
+    occupancy and capacity planes (each server its own capacity) and
+    ``(Qcap, R)`` queued demand vectors;
   * ``engine="pallas"``    — the fused slot-step kernel in
     ``kernels/bfjs_mr`` (occupancy planes, queue state and counters stay
     resident in VMEM; the Monte-Carlo ensemble is the kernel grid), which
@@ -60,6 +61,7 @@ from .bfjs import DEFAULT_MAX_REQUEUE
 from .ops import alignment_score_pair_jnp
 from .streams import (INF_SLOT, PolicyResult, SchedStreams, make_streams,
                       resolve_work_steps)
+from .workload import capacity_plane
 
 INT32_MAX = jnp.iinfo(jnp.int32).max
 
@@ -105,17 +107,6 @@ def _preempt_planes(dem, dep, occ, qdem, qdur, qseq, qtry, tries, sseq,
             q_cnt, n_vict, n_req, n_vict - n_req)
 
 
-def _norm_capacity(capacity, R: int) -> tuple[float, ...]:
-    if not isinstance(capacity, tuple):
-        capacity = (float(capacity),) * R
-    if len(capacity) != R:
-        raise ValueError(
-            f"capacity has {len(capacity)} entries for R={R} resources")
-    if any(c <= 0 for c in capacity):
-        raise ValueError(f"capacity entries must be > 0, got {capacity}")
-    return tuple(float(c) for c in capacity)
-
-
 def _lift_sizes(streams: SchedStreams) -> SchedStreams:
     """bfjs-mr consumes (T, A_max, R) sizes; lift squeezed R=1 streams."""
     if streams.sizes.ndim == streams.durs.ndim:
@@ -123,17 +114,19 @@ def _lift_sizes(streams: SchedStreams) -> SchedStreams:
     return streams
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("L", "K", "Qcap", "A_max", "work_steps", "capacity",
-                     "max_requeue", "return_state"))
 def run_bfjs_mr_streams(streams: SchedStreams, L: int, K: int, Qcap: int,
                         A_max: int, work_steps: int | None = None,
-                        capacity: tuple[float, ...] | float = 1.0,
+                        capacity=1.0,
                         max_requeue: int = DEFAULT_MAX_REQUEUE,
                         state: tuple | None = None,
                         return_state: bool = False):
     """Branch-free multi-resource BF-J/S slot engine over streams.
+
+    ``capacity`` is a scalar, a length-R tuple or an ``(L, R)`` per-server
+    plane; it enters the program as the ``capacity_plane`` grid integers.
+    The result's ``steps`` counts the work steps run and
+    ``bfs_placements`` the placements BF-S refills made, both summed over
+    the slots (and carried in ``state`` across chunks).
 
     One ``lax.scan`` over slots; inside each slot the BF-S refill and BF-J
     placement passes are a bounded early-exit work list
@@ -147,9 +140,24 @@ def run_bfjs_mr_streams(streams: SchedStreams, L: int, K: int, Qcap: int,
     lowest-index-first order reproduces the oracle's nested loops exactly.
     """
     streams = _lift_sizes(streams)
+    cap = capacity_plane(capacity, L, int(streams.sizes.shape[-1]))
+    return _run_bfjs_mr_streams(streams, jnp.asarray(cap), L=L, K=K,
+                                Qcap=Qcap, A_max=A_max,
+                                work_steps=work_steps,
+                                max_requeue=max_requeue, state=state,
+                                return_state=return_state)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("L", "K", "Qcap", "A_max", "work_steps",
+                     "max_requeue", "return_state"))
+def _run_bfjs_mr_streams(streams: SchedStreams, cap: jax.Array, L: int,
+                         K: int, Qcap: int, A_max: int,
+                         work_steps: int | None, max_requeue: int,
+                         state: tuple | None, return_state: bool):
+    """``run_bfjs_mr_streams`` on the ``(L, R)`` int32 capacity plane."""
     horizon, _, R = streams.sizes.shape
-    capacity = _norm_capacity(capacity, R)
-    CAP = jnp.asarray([round(c * RES) for c in capacity], jnp.int32)
     W = resolve_work_steps(work_steps, A_max)
     faulted = streams.up is not None
     a_iota = jnp.arange(A_max)
@@ -160,7 +168,8 @@ def run_bfjs_mr_streams(streams: SchedStreams, L: int, K: int, Qcap: int,
 
     def slot_step(state, inp):
         (dem, dep, occ, qdem, qdur, qseq, t, q_cnt, seq0, dropped, trunc,
-         qtry, tries, sseq, preempted, requeued, lost, up_last) = state
+         qtry, tries, sseq, preempted, requeued, lost, up_last, steps,
+         bfs) = state
         if faulted:
             n, sizes, durs, up_t = inp
         else:
@@ -214,7 +223,7 @@ def run_bfjs_mr_streams(streams: SchedStreams, L: int, K: int, Qcap: int,
 
         def fits_matrix(occ, qdem, qseq, freed_mask):
             """(L, Qcap) — job j fits on server i (static unroll over R)."""
-            avail = CAP[None, :] - occ
+            avail = cap - occ
             fits = freed_mask[:, None] & (qseq >= 0)[None, :]
             for r in range(R):
                 fits = fits & (qdem[:, r][None, :] <= avail[:, r][:, None])
@@ -223,8 +232,8 @@ def run_bfjs_mr_streams(streams: SchedStreams, L: int, K: int, Qcap: int,
         # 3+4. BF-S then BF-J as one bounded early-exit work list
         def work(carry):
             (dem, dep, occ, qdem, qdur, qseq, qtry, tries, sseq, q_cnt,
-             blocked, a_ptr, trunc, done, n_steps) = carry
-            avail = CAP[None, :] - occ
+             blocked, a_ptr, trunc, bfs, done, n_steps) = carry
+            avail = cap - occ
 
             # BF-S candidate: lowest-index freed, unblocked server with a
             # fitting job; its job = largest total demand, earliest seq.
@@ -288,6 +297,7 @@ def run_bfjs_mr_streams(streams: SchedStreams, L: int, K: int, Qcap: int,
             qdem = qdem.at[qclear].set(0, mode="drop")
             qtry = qtry.at[qclear].set(0, mode="drop")
             q_cnt = q_cnt - place.astype(jnp.int32)
+            bfs = bfs + (place & any_bfs).astype(jnp.int32)
             # K-full server: the oracle would place; count, don't spin.
             trunc = trunc + (do & ~ok_slot).astype(jnp.int32)
             blocked = blocked | (any_bfs & ~ok_slot)
@@ -296,19 +306,20 @@ def run_bfjs_mr_streams(streams: SchedStreams, L: int, K: int, Qcap: int,
             # once neither exists the slot is finished for good.
             done = (~any_bfs) & (a_ptr >= n_landed)
             return (dem, dep, occ, qdem, qdur, qseq, qtry, tries, sseq,
-                    q_cnt, blocked, a_ptr, trunc, done, n_steps + 1)
+                    q_cnt, blocked, a_ptr, trunc, bfs, done, n_steps + 1)
 
         def unfinished(carry):
-            done, n_steps = carry[13], carry[14]
+            done, n_steps = carry[14], carry[15]
             return (~done) & (n_steps < W)
 
         zero = jnp.zeros((), jnp.int32)
         carry = (dem, dep, occ, qdem, qdur, qseq, qtry, tries, sseq,
-                 q_cnt, jnp.zeros((L,), bool), zero, trunc,
+                 q_cnt, jnp.zeros((L,), bool), zero, trunc, bfs,
                  jnp.zeros((), bool), zero)
         carry = jax.lax.while_loop(unfinished, work, carry)
         (dem, dep, occ, qdem, qdur, qseq, qtry, tries, sseq, q_cnt,
-         blocked, a_ptr, trunc, done, _) = carry
+         blocked, a_ptr, trunc, bfs, done, n_steps) = carry
+        steps = steps + n_steps
 
         # saturation check: work the oracle would still do => the bounded
         # list diverged this slot (K-full blocks were already counted).
@@ -317,7 +328,7 @@ def run_bfjs_mr_streams(streams: SchedStreams, L: int, K: int, Qcap: int,
         left = (a_iota >= a_ptr) & (a_iota < n_landed)
         posb = jnp.maximum(pos_list, 0)
         present_l = left & (pos_list >= 0) & (qseq[posb] >= 0)
-        avail = CAP[None, :] - occ
+        avail = cap - occ
         feas_l = jnp.ones((A_max, L), bool)
         for r in range(R):
             feas_l = feas_l & (qdem[posb][:, r][:, None]
@@ -331,7 +342,7 @@ def run_bfjs_mr_streams(streams: SchedStreams, L: int, K: int, Qcap: int,
                n_dep.astype(jnp.int32))
         state = (dem, dep, occ, qdem, qdur, qseq, t + 1, q_cnt, seq0,
                  dropped, trunc, qtry, tries, sseq, preempted, requeued,
-                 lost, up_last)
+                 lost, up_last, steps, bfs)
         return state, out
 
     zero = jnp.zeros((), jnp.int32)
@@ -349,18 +360,20 @@ def run_bfjs_mr_streams(streams: SchedStreams, L: int, K: int, Qcap: int,
             jnp.zeros((L, K), jnp.int32),    # sseq: in-service seq ids
             zero, zero, zero,                # preempted / requeued / lost
             jnp.ones((L,), bool),            # up_last (recovery detection)
+            zero, zero,                      # steps / bfs_placements
         )
     xs = (streams.n, streams.sizes, streams.durs)
     if faulted:
         xs = xs + (streams.up,)
     state, (qlen, occ, ndep) = jax.lax.scan(slot_step, state, xs)
     res = PolicyResult(qlen, occ, jnp.cumsum(ndep), state[9], state[10],
-                       state[14], state[15], state[16])
+                       state[14], state[15], state[16], steps=state[18],
+                       bfs_placements=state[19])
     return (res, state) if return_state else res
 
 
 def _run_bfjs_mr_reference(streams: SchedStreams, *, L: int,
-                           capacity: tuple[float, ...] | float = 1.0,
+                           capacity=1.0,
                            max_requeue: int = DEFAULT_MAX_REQUEUE
                            ) -> PolicyResult:
     """The event-driven ``MultiResourceBFJS`` oracle driven from streams.
@@ -368,11 +381,11 @@ def _run_bfjs_mr_reference(streams: SchedStreams, *, L: int,
     Host-side numpy, slot by slot — not jittable, kept as the behavioural
     anchor the scan engine is parity-tested against.  Demands are the same
     grid quantization the scan engine applies (``max(round(s * RES), 1)``)
-    replayed as exact dyadics ``g / RES``; the capacity is quantized to the
-    grid too, so every feasibility comparison is exact and agrees with the
-    integer engine.  When the streams carry a fault plane the oracle is
-    stepped with ``down = ~up[t]`` and the counters come from its fault
-    accounting (lost jobs never depart, so cumulative departures subtract
+    replayed as exact dyadics ``g / RES``; the capacity plane is the
+    engines' ``capacity_plane`` over ``RES``, so every feasibility
+    comparison is exact and agrees with the integer engine.  When the
+    streams carry a fault plane the oracle is stepped with ``down =
+    ~up[t]`` and the counters come from its fault accounting (lost jobs never depart, so cumulative departures subtract
     them).  The oracle has no fixed-size buffers: ``dropped`` and
     ``truncated`` are always 0.
     """
@@ -384,8 +397,7 @@ def _run_bfjs_mr_reference(streams: SchedStreams, *, L: int,
     durs = np.asarray(streams.durs)
     up = None if streams.up is None else np.asarray(streams.up)
     T, A_max, R = sizes.shape
-    capacity = _norm_capacity(capacity, R)
-    cap_dyadic = tuple(round(c * RES) / RES for c in capacity)
+    cap_dyadic = capacity_plane(capacity, L, R) / RES
     g = np.maximum(np.rint(sizes * RES), 1.0)
     dem = g / RES
     dur_off = durs.shape[-1] - A_max
@@ -412,13 +424,14 @@ def _run_bfjs_mr_reference(streams: SchedStreams, *, L: int,
         jnp.asarray(qlen), jnp.asarray(occ.astype(np.float32)),
         jnp.asarray(dep_cum), jnp.zeros((), jnp.int32),
         jnp.zeros((), jnp.int32), i32(policy.preempted),
-        i32(policy.requeued), i32(policy.lost))
+        i32(policy.requeued), i32(policy.lost), steps=i32(policy.steps),
+        bfs_placements=i32(policy.bfs_placements))
 
 
 def run_bfjs_mr_trace(streams: SchedStreams, *, L: int, K: int = 16,
                       Qcap: int = 512, A_max: int | None = None,
                       engine: str = "scan", work_steps: int | None = None,
-                      capacity: tuple[float, ...] | float = 1.0,
+                      capacity=1.0,
                       window: int | None = None,
                       max_requeue: int = DEFAULT_MAX_REQUEUE,
                       strict: bool = False) -> PolicyResult:
@@ -429,10 +442,12 @@ def run_bfjs_mr_trace(streams: SchedStreams, *, L: int, K: int = 16,
     ``make_streams`` full-width streams (the engine consumes the last
     ``A_max`` per-arrival lanes; durations attach at arrival).  ``window``
     is the Pallas engine's VMEM time-window length (must divide the
-    horizon; ignored by the other engines).  ``engine="pallas"`` is gated
-    by :func:`repro.kernels.common.pallas_precheck` — a fault plane or an
-    over-budget VMEM estimate degrades to the bit-identical scan engine
-    with a :class:`GracefulDegradationWarning` (or raises, ``strict=True``).
+    horizon; ignored by the other engines).  ``capacity`` is a scalar, a
+    length-R tuple or an ``(L, R)`` per-server plane.  ``engine="pallas"``
+    is gated by :func:`repro.kernels.common.pallas_precheck` — a fault
+    plane or an over-budget VMEM estimate degrades to the bit-identical
+    scan engine with a :class:`GracefulDegradationWarning` (or raises,
+    ``strict=True``).
     """
     streams = _lift_sizes(streams)
     if A_max is None:
@@ -461,8 +476,6 @@ def run_bfjs_mr_trace(streams: SchedStreams, *, L: int, K: int = 16,
                                    capacity=capacity, window=window)
             return jax.tree.map(lambda x: x[0], res)
     if engine == "scan":
-        if not isinstance(capacity, tuple):
-            capacity = _norm_capacity(capacity, int(streams.sizes.shape[-1]))
         return run_bfjs_mr_streams(streams, L=L, K=K, Qcap=Qcap,
                                    A_max=A_max, work_steps=work_steps,
                                    capacity=capacity,

@@ -112,7 +112,8 @@ def _append(partial: PolicyResult | None, res: PolicyResult,
         jnp.concatenate([partial.occupancy, res.occupancy], axis=axis),
         jnp.concatenate([partial.departed, res.departed + dep_off],
                         axis=axis),
-        res.dropped, res.truncated, res.preempted, res.requeued, res.lost)
+        res.dropped, res.truncated, res.preempted, res.requeued, res.lost,
+        steps=res.steps, bfs_placements=res.bfs_placements)
 
 
 def _save_step(checkpoint_dir: str, step: int, payload: Any,
@@ -184,12 +185,11 @@ def run_chunked(streams: SchedStreams, *, policy: str = "bfjs",
                          "G axis on every plane); single-run streams have "
                          "nothing to shard")
     if policy == "bfjs-mr":
-        from .bfjs_mr import _lift_sizes, _norm_capacity
+        from .bfjs_mr import _lift_sizes
+        from .workload import normalize_capacity
         streams = _lift_sizes(streams)
-        cap = config.get("capacity", 1.0)
-        if not isinstance(cap, tuple):
-            config["capacity"] = _norm_capacity(
-                cap, int(streams.sizes.shape[-1]))
+        config["capacity"] = normalize_capacity(
+            config.get("capacity", 1.0), int(streams.sizes.shape[-1]))
     config.setdefault("A_max", int(streams.sizes.shape[streams.n.ndim]))
     T = int(streams.n.shape[-1])
     bounds = [(lo, min(lo + chunk, T)) for lo in range(0, T, chunk)]

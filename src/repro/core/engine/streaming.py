@@ -406,10 +406,9 @@ def stream_policy(chunks: Iterable, *, policy: str = "bfjs",
 
     ensemble, G, lanes, n_res = _chunk_shape(first)
     if policy == "bfjs-mr":
-        from .bfjs_mr import _norm_capacity
-        cap = config.get("capacity", 1.0)
-        if not isinstance(cap, tuple):
-            config["capacity"] = _norm_capacity(cap, max(n_res, 1))
+        from .workload import normalize_capacity
+        config["capacity"] = normalize_capacity(
+            config.get("capacity", 1.0), max(n_res, 1))
     config.setdefault("A_max", lanes)
     from .tuning import apply_tuned
     apply_tuned(policy, "scan", config, max(n_res, 1))

@@ -30,7 +30,6 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-import numpy as np
 
 INF_SLOT = jnp.iinfo(jnp.int32).max
 
@@ -119,6 +118,19 @@ class PolicyResult(NamedTuple):
     rollbacks: int | None = None        # corrupt checkpoint boundaries
     #                                     skipped on resume to reach the
     #                                     last good one
+    # Work counters of ``bfjs-mr`` (() int32 per member, summed over the
+    # slots; None for the other policies):
+    steps: jax.Array | None = None      # work steps of the bounded
+    #                                     placement work list (the oracle
+    #                                     reports the steps its placements
+    #                                     need: one per BF-S placement, one
+    #                                     per arrival's BF-J attempt, at
+    #                                     least one a slot — equal to the
+    #                                     engines' while truncated == 0)
+    bfs_placements: jax.Array | None = None  # placements made by BF-S
+    #                                     refills rather than by BF-J at
+    #                                     arrival (a policy quantity: every
+    #                                     engine reports the same value)
 
 
 #: Back-compat alias (PR 1 public name).

@@ -330,7 +330,7 @@ INVARIANTS = (
     ("in_flight_bound",
      "in-flight jobs <= L*K server slots (job conservation)"),
     ("occupancy_capacity",
-     "occupancy <= L*capacity per resource"),
+     "occupancy <= the servers' total capacity per resource"),
     ("preempted_split",
      "preempted == requeued + lost (fault accounting)"),
     ("queue_nonneg", "queue_len >= 0"),
@@ -379,10 +379,11 @@ def make_auditor(*, policy: str, config: dict, num_resources: int,
             "conservation bounds are physical (L*K server slots, "
             "L*capacity occupancy) and cannot be inferred from engine "
             "defaults") from e
-    cap = config.get("capacity", 1.0)
-    if not isinstance(cap, (tuple, list)):
-        cap = (float(cap),) * num_resources
-    cap_total = jnp.asarray(np.asarray(cap, dtype=np.float32) * L)
+    # per-resource total over the servers (a per-server plane or one
+    # capacity shared by all L)
+    cap = np.asarray(config.get("capacity", 1.0), dtype=np.float64)
+    cap_total = jnp.asarray(np.broadcast_to(cap, (L, num_resources))
+                            .sum(axis=0).astype(np.float32))
     max_in_flight = float(L * K)
 
     @jax.jit
@@ -431,11 +432,7 @@ def audit_result(streams, res, *, policy: str, config: dict) -> None:
     Not for ``trajectory="tail"`` streaming results — their planes cover
     only the newest chunk while ``streams`` covers the full horizon."""
     n_res = res.occupancy.ndim - res.queue_len.ndim + 1
-    cfg = dict(config)
-    if cfg.get("capacity") is not None \
-            and not isinstance(cfg["capacity"], (tuple, list)):
-        cfg["capacity"] = (float(cfg["capacity"]),) * n_res
-    audit = make_auditor(policy=policy, config=cfg, num_resources=n_res,
+    audit = make_auditor(policy=policy, config=config, num_resources=n_res,
                          what="one-shot run")
     # a partial result (stop_after_chunks) covers fewer slots than the
     # streams — count arrivals only over the horizon the result covers

@@ -85,18 +85,21 @@ class MultiResourceBFJS:
     """BF-J/S with the alignment score over R resources.
 
     BF-S step (freed servers): repeatedly place the queued job with the
-    highest alignment that fits.  BF-J step (new jobs): place on the
-    highest-alignment feasible server.
+    largest total demand that fits.  BF-J step (new jobs): place on the
+    feasible server with the lowest alignment score.  ``capacity`` is a
+    scalar, a length-R tuple or an ``(L, R)`` plane with one row per
+    server; feasibility and availability are each server's own.
     """
 
     name = "mr-bf-js"
 
-    def __init__(self, L: int, num_resources: int,
-                 capacity: float | tuple[float, ...] = 1.0):
+    def __init__(self, L: int, num_resources: int, capacity=1.0):
         self.L = L
         self.R = num_resources
+        # (L, R): one row per server; a scalar or a length-R capacity is
+        # the same on every server
         self.capacity = np.broadcast_to(
-            np.asarray(capacity, dtype=np.float64), (num_resources,)).copy()
+            np.asarray(capacity, dtype=np.float64), (L, num_resources)).copy()
         self.occupied = np.zeros((L, num_resources))
         self.jobs: list[dict[int, MRJob]] = [dict() for _ in range(L)]
         self.queue: dict[int, MRJob] = {}
@@ -109,13 +112,19 @@ class MultiResourceBFJS:
         self.preempted = 0
         self.requeued = 0
         self.lost = 0
+        # placements made by BF-S refills (the rest are BF-J's), and the
+        # work steps the engines' bounded work list needs for them: one
+        # per BF-S placement, one per arrival's BF-J attempt, at least one
+        # a slot (the step that finds no work)
+        self.bfs_placements = 0
+        self.steps = 0
         self._seq = 0
         self._down_last = np.zeros(L, dtype=bool)
 
     # -- scores -------------------------------------------------------------
     def _feasible(self, demand: np.ndarray) -> np.ndarray:
         return (self.occupied + demand[None, :]
-                <= self.capacity[None, :] + 1e-12).all(axis=1)
+                <= self.capacity + 1e-12).all(axis=1)
 
     def _best_server(self, demand: np.ndarray,
                      down: np.ndarray | None = None) -> int:
@@ -124,7 +133,7 @@ class MultiResourceBFJS:
             feas = feas & ~down
         if not feas.any():
             return -1
-        avail = self.capacity[None, :] - self.occupied
+        avail = self.capacity - self.occupied
         # tightest-in-needed-dims = argmin of the exact alignment score
         # (order-independent — see alignment_scores)
         scores = alignment_scores(avail, demand)
@@ -137,9 +146,10 @@ class MultiResourceBFJS:
         if not self.queue:
             return None
         occ = self.occupied[server]
+        cap = self.capacity[server]
         best, best_s = None, -np.inf
         for job in self.queue.values():
-            if np.all(occ + job.demand <= self.capacity + 1e-12):
+            if np.all(occ + job.demand <= cap + 1e-12):
                 s = float(job.demand.sum())
                 if s > best_s:
                     best, best_s = job, s
@@ -209,6 +219,9 @@ class MultiResourceBFJS:
                     break
                 del self.queue[job.jid]
                 self._place(t, server, job)
+                self.bfs_placements += 1
+                self.steps += 1
+        self.steps += max(len(new_jobs), 1)
         # BF-J over new arrivals still queued
         for job in new_jobs:
             if job.jid in self.queue:

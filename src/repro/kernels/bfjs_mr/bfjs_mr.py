@@ -16,10 +16,11 @@ The placement logic transcribes the bounded early-exit work list of
 ``repro.core.engine.bfjs_mr.run_bfjs_mr_streams`` with broadcasted-iota
 masks and reductions in place of every dynamic index, and the resource
 axis STATICALLY UNROLLED: vector state is stored as R stacked 2D planes
-(demands ``(R, L, K)``, one ``(L, K)`` plane per resource, and queue
-demands ``(R, Qcap)``), so every per-resource feasibility comparison is a
-plain 2D vector op.  The Tetris alignment score is exact integer
-arithmetic compared as a normalized int32 ``(hi, lo)`` pair — the same
+(demands ``(R, L, K)``, one ``(L, K)`` plane per resource, queue
+demands ``(R, Qcap)``, and the per-server capacity input ``(R, L, 1)``),
+so every per-resource feasibility comparison is a plain 2D vector op
+against each server's own capacity.  The Tetris alignment score is exact
+integer arithmetic compared as a normalized int32 ``(hi, lo)`` pair — the same
 scheme as ``engine.ops.alignment_score_pair_jnp`` — so argmin tie-breaks
 bit-match the scan engine (and, through it, the event-driven
 ``MultiResourceBFJS`` oracle) on every backend and lowering.  Trajectories
@@ -50,22 +51,23 @@ def bfjs_mr_vmem_bytes(L: int, K: int, Qcap: int, A_max: int, R: int,
                        TW: int) -> int:
     """VMEM the fused multi-resource BF-J/S kernel takes on the chip: the
     scratch state (demand (R,L,K), dep (L,K), occupancy (R,L,1), queue
-    demand (R,Qcap), queue meta (2,Qcap)), the double-buffered
-    (TW, A_max*R) size and (TW, A_max) duration blocks, and the compiler's
-    spills — 12 (L,128) planes and three (A_max,Qcap) planes of the
-    arrival-to-queue gathers.  All padded to (8,128) tiles.  The spill
-    counts are fitted (at R=2) to the v5e compiler's allocation and kept
-    honest by tests/test_tpu_compile.py."""
-    return ((R + 1) * tile_bytes(L, K) + R * tile_bytes(L, 1)
+    demand (R,Qcap), queue meta (2,Qcap)), the double-buffered capacity
+    input (R,L,1) and (TW, A_max*R) size and (TW, A_max) duration blocks,
+    and the compiler's spills — 12 (L,128) planes and three (A_max,Qcap)
+    planes of the arrival-to-queue gathers.  All padded to (8,128) tiles.
+    The spill counts are fitted (at R=2) to the v5e compiler's allocation
+    and kept honest by tests/test_tpu_compile.py."""
+    return ((R + 1) * tile_bytes(L, K) + 3 * R * tile_bytes(L, 1)
             + tile_bytes(R, Qcap) + tile_bytes(2, Qcap)
             + stream_block_bytes(TW, A_max * R, A_max)
             + 12 * tile_bytes(L, LANES) + 3 * tile_bytes(A_max, Qcap))
 
 
-def _bfjs_mr_kernel(n_ref, sizes_ref, durs_ref,
+def _bfjs_mr_kernel(n_ref, sizes_ref, durs_ref, cap_ref,
                     qlen_ref, occ_out_ref, ndep_ref, dropped_ref, trunc_ref,
+                    steps_ref, bfs_ref,
                     dem_ref, dep_ref, occ_ref, qdem_ref, qmeta_ref, acc_ref,
-                    *, L, K, R, Qcap, A_max, W, TW, CAP, EARLY_EXIT):
+                    *, L, K, R, Qcap, A_max, W, TW, EARLY_EXIT):
     w = pl.program_id(1)
 
     @pl.when(w == 0)
@@ -77,7 +79,7 @@ def _bfjs_mr_kernel(n_ref, sizes_ref, durs_ref,
         row = jax.lax.broadcasted_iota(jnp.int32, (2, Qcap), 0)
         # row 0: qdur (init 1), row 1: qseq (init -1)
         qmeta_ref[...] = jnp.where(row == 0, 1, -1)
-        for i in range(4):
+        for i in range(6):
             acc_ref[i] = 0
 
     l_col = jax.lax.broadcasted_iota(jnp.int32, (L, 1), 0)
@@ -102,7 +104,7 @@ def _bfjs_mr_kernel(n_ref, sizes_ref, durs_ref,
                                  jnp.zeros((L, 1), jnp.int32)) != 0
 
     def slot_step(tt, carry):
-        q_cnt, seq0, dropped, trunc = carry
+        q_cnt, seq0, dropped, trunc, steps, bfs = carry
         t = w * TW + tt
 
         # 1. departures free their demand vectors
@@ -158,7 +160,7 @@ def _bfjs_mr_kernel(n_ref, sizes_ref, durs_ref,
         # demand, earliest seq), else attempts the next landed arrival on
         # the min-alignment feasible server.
         def work(wcarry):
-            step, a_ptr, blocked, q_cnt, trunc, _ = wcarry
+            step, a_ptr, blocked, q_cnt, trunc, bfs, done_in = wcarry
             # the (L, 1) mask rides the loop as int32: Mosaic cannot carry
             # bool vectors across loop iterations
             blocked = blocked != 0
@@ -166,7 +168,7 @@ def _bfjs_mr_kernel(n_ref, sizes_ref, durs_ref,
             qdem = qdem_ref[...]
             qmeta = qmeta_ref[...]
             qdur, qseq = qmeta[0:1], qmeta[1:2]
-            avail = [CAP[r] - occ_ref[r] for r in range(R)]  # (L, 1)
+            avail = [cap_ref[r] - occ_ref[r] for r in range(R)]  # (L, 1)
 
             # BF-S candidate: the fit row of server `cur`
             cur = jnp.min(jnp.where(has_fit(freed & ~blocked, avail), l_col,
@@ -190,11 +192,6 @@ def _bfjs_mr_kernel(n_ref, sizes_ref, durs_ref,
             # the min-alignment feasible server (any server, not just
             # freed — the oracle's _best_server scans all L).
             is_bfj = (~any_bfs) & (a_ptr < n_landed)
-            # The scan engine's early-exit rule: with no BF-S fit left and
-            # every landed arrival consumed, no later step can do work
-            # (queues only shrink, avail only shrinks, freed&~blocked only
-            # shrinks), so remaining steps are no-ops.
-            done = (~any_bfs) & (a_ptr >= n_landed)
             ap = jnp.minimum(a_ptr, A_max - 1)
             pos = jnp.max(jnp.where(a_row == ap, pos_list, -1))
             posc = jnp.maximum(pos, 0)
@@ -251,30 +248,41 @@ def _bfjs_mr_kernel(n_ref, sizes_ref, durs_ref,
             qmeta_ref[...] = jnp.concatenate(
                 [qdur, jnp.where(clr, -1, qseq)], axis=0)
             q_cnt = q_cnt - place.astype(jnp.int32)
+            bfs = bfs + (place & any_bfs).astype(jnp.int32)
             # K-full server: the oracle would place; count, don't spin.
             trunc = trunc + (do & ~ok_slot).astype(jnp.int32)
             blocked = blocked | (any_bfs & ~ok_slot)
             a_ptr = a_ptr + is_bfj.astype(jnp.int32)
-            return (step + 1, a_ptr, blocked.astype(jnp.int32), q_cnt, trunc,
-                    done)
+            # The scan engine's early-exit rule: with no BF-S fit left and
+            # every landed arrival consumed, no later step can do work
+            # (queues only shrink, avail only shrinks, freed&~blocked only
+            # shrinks), so remaining steps are no-ops.
+            done = (~any_bfs) & (a_ptr >= n_landed)
+            # a step counts until one reports done (the no-op steps the
+            # fixed-bound loop runs after it are not work)
+            return (step + jnp.where(done_in, 0, 1), a_ptr,
+                    blocked.astype(jnp.int32), q_cnt, trunc, bfs, done)
 
         winit = (jnp.int32(0), jnp.int32(0), jnp.zeros((L, 1), jnp.int32),
-                 q_cnt, trunc, jnp.bool_(False))
+                 q_cnt, trunc, bfs, jnp.bool_(False))
         if EARLY_EXIT:
             # Same body, but stop as soon as a step reports done — the
             # scan engine exits here too, and post-done steps are no-ops,
             # so the trajectory is bit-identical by construction.
-            _, a_ptr, blocked, q_cnt, trunc, _ = jax.lax.while_loop(
-                lambda c: (c[0] < W) & jnp.logical_not(c[-1]), work, winit)
+            n_steps, a_ptr, blocked, q_cnt, trunc, bfs, _ = \
+                jax.lax.while_loop(
+                    lambda c: (c[0] < W) & jnp.logical_not(c[-1]), work,
+                    winit)
         else:
-            _, a_ptr, blocked, q_cnt, trunc, _ = jax.lax.fori_loop(
-                0, W, lambda _, c: work(c), winit)
+            n_steps, a_ptr, blocked, q_cnt, trunc, bfs, _ = \
+                jax.lax.fori_loop(0, W, lambda _, c: work(c), winit)
+        steps = steps + n_steps
 
         # saturation check (same rule as the scan engine): work the oracle
         # would still do => the bounded list diverged this slot.
         qdem = qdem_ref[...]
         qseq = qmeta_ref[...][1:2]
-        avail = [CAP[r] - occ_ref[r] for r in range(R)]
+        avail = [cap_ref[r] - occ_ref[r] for r in range(R)]
         pend_bfs = has_fit(freed & (blocked == 0), avail).any()
         left = (a_row >= a_ptr) & (a_row < n_landed)
         gmask = aq == jnp.maximum(pos_list, 0).T             # (A_max, Qcap)
@@ -292,36 +300,35 @@ def _bfjs_mr_kernel(n_ref, sizes_ref, durs_ref,
         for r in range(R):
             occ_out_ref[r, tt] = jnp.sum(occ_ref[r]).astype(jnp.float32) / RES
         ndep_ref[0, tt] = n_dep.astype(jnp.int32)
-        return q_cnt, seq0, dropped, trunc
+        return q_cnt, seq0, dropped, trunc, steps, bfs
 
     carry = jax.lax.fori_loop(
-        0, TW, slot_step, tuple(acc_ref[i] for i in range(4)))
+        0, TW, slot_step, tuple(acc_ref[i] for i in range(6)))
     for i, v in enumerate(carry):
         acc_ref[i] = v
-    q_cnt, seq0, dropped, trunc = carry
-    dropped_ref[0, 0] = dropped
-    trunc_ref[0, 0] = trunc
+    dropped_ref[0, 0], trunc_ref[0, 0] = carry[2], carry[3]
+    steps_ref[0, 0], bfs_ref[0, 0] = carry[4], carry[5]
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("L", "K", "Qcap", "A_max", "work_steps", "capacity",
-                     "window", "interpret", "early_exit"))
+    static_argnames=("L", "K", "Qcap", "A_max", "work_steps", "window",
+                     "interpret", "early_exit"))
 def bfjs_mr_pallas(n: jax.Array, sizes: jax.Array, durs: jax.Array,
-                   L: int, K: int, Qcap: int, A_max: int,
-                   work_steps: int, capacity: tuple[float, ...],
-                   window: int | None = None, interpret: bool = False,
-                   early_exit: bool = True):
+                   cap: jax.Array, L: int, K: int, Qcap: int, A_max: int,
+                   work_steps: int, window: int | None = None,
+                   interpret: bool = False, early_exit: bool = True):
     """Run the fused multi-resource BF-J/S slot engine on an ensemble.
 
     n (G, T) int32, sizes (G, T, A_max, R) f32, durs (G, T, D) int32 with
     the per-arrival durations in the last A_max lanes (D = A_max for
     streams_from_trace, D = L*K+A_max for make_streams) — one pre-generated
     stream set per ensemble member (only those lanes are streamed into
-    the kernel).  ``capacity`` is the per-resource
-    server capacity tuple (length R).  Returns per-slot (queue_len (G, T),
-    occupancy (G, T, R), departures (G, T)) plus (dropped, truncated) of
-    shape (G,).
+    the kernel).  ``cap`` is the (L, R) int32 per-server capacity plane on
+    the ``quantize.RES`` grid (``core.engine.workload.capacity_plane``),
+    held in VMEM as R (L, 1) planes.  Returns per-slot (queue_len (G, T),
+    occupancy (G, T, R), departures (G, T)) plus (dropped, truncated,
+    steps, bfs_placements) of shape (G,).
 
     ``window`` splits the horizon into VMEM-sized chunks: the grid is
     (G, T//window) and simulation state persists in scratch across a
@@ -331,40 +338,42 @@ def bfjs_mr_pallas(n: jax.Array, sizes: jax.Array, durs: jax.Array,
     G, T, A_sz, R = sizes.shape
     if A_sz != A_max:
         raise ValueError(f"sizes carry A_max={A_sz}, expected {A_max}")
-    if len(capacity) != R:
+    if cap.shape != (L, R):
         raise ValueError(
-            f"capacity has {len(capacity)} entries for R={R} resources")
+            f"capacity plane of shape {cap.shape}, expected (L, R) = "
+            f"{(L, R)}")
     TW, NW = resolve_windows(T, window)
     D = durs.shape[-1]
-    CAP = tuple(round(c * RES) for c in capacity)
     kernel = functools.partial(
         _bfjs_mr_kernel, L=L, K=K, R=R, Qcap=Qcap, A_max=A_max,
-        W=work_steps, TW=TW, CAP=CAP, EARLY_EXIT=early_exit)
-    qlen, occ, ndep, dropped, trunc = pl.pallas_call(
+        W=work_steps, TW=TW, EARLY_EXIT=early_exit)
+    qlen, occ, ndep, dropped, trunc, steps, bfs = pl.pallas_call(
         kernel,
         grid=(G, NW),
         out_shape=(slot_out_shape(G, T, TW, jnp.int32),
                    jax.ShapeDtypeStruct((G, NW, R, TW), jnp.float32),
-                   slot_out_shape(G, T, TW, jnp.int32),
-                   jax.ShapeDtypeStruct((G, 1, 1), jnp.int32),
-                   jax.ShapeDtypeStruct((G, 1, 1), jnp.int32)),
+                   slot_out_shape(G, T, TW, jnp.int32))
+                  + (jax.ShapeDtypeStruct((G, 1, 1), jnp.int32),) * 4,
         in_specs=[slot_spec(TW),
                   pl.BlockSpec((1, TW, A_max * R), lambda g, w: (g, w, 0)),
-                  pl.BlockSpec((1, TW, A_max), lambda g, w: (g, w, 0))],
+                  pl.BlockSpec((1, TW, A_max), lambda g, w: (g, w, 0)),
+                  pl.BlockSpec((R, L, 1), lambda g, w: (0, 0, 0))],
         out_specs=(slot_spec(TW),
                    pl.BlockSpec((None, None, R, TW),
                                 lambda g, w: (g, w, 0, 0),
                                 memory_space=pltpu.SMEM),
-                   slot_spec(TW), counter_spec(), counter_spec()),
+                   slot_spec(TW)) + (counter_spec(),) * 4,
         scratch_shapes=[pltpu.VMEM((R, L, K), jnp.int32),
                         pltpu.VMEM((L, K), jnp.int32),
                         pltpu.VMEM((R, L, 1), jnp.int32),
                         pltpu.VMEM((R, Qcap), jnp.int32),
                         pltpu.VMEM((2, Qcap), jnp.int32),
-                        pltpu.SMEM((4,), jnp.int32)],
+                        pltpu.SMEM((6,), jnp.int32)],
         compiler_params=compiler_params(
             bfjs_mr_vmem_bytes(L, K, Qcap, A_max, R, TW)),
         interpret=interpret,
-    )(to_windows(n, TW), sizes.reshape(G, T, A_max * R), durs[..., D - A_max:])
+    )(to_windows(n, TW), sizes.reshape(G, T, A_max * R), durs[..., D - A_max:],
+      cap.astype(jnp.int32).T[..., None])
     return (qlen.reshape(G, T), occ.transpose(0, 1, 3, 2).reshape(G, T, R),
-            ndep.reshape(G, T), dropped[:, 0, 0], trunc[:, 0, 0])
+            ndep.reshape(G, T), dropped[:, 0, 0], trunc[:, 0, 0],
+            steps[:, 0, 0], bfs[:, 0, 0])

@@ -14,7 +14,7 @@ from repro.core.engine.streams import PolicyResult, SchedStreams
 
 def bfjs_mr_ref(n, sizes, durs, L: int, K: int, Qcap: int, A_max: int,
                 work_steps: int | None = None,
-                capacity: tuple[float, ...] = (1.0,)) -> PolicyResult:
+                capacity=1.0) -> PolicyResult:
     """n (G, T) int32, sizes (G, T, A_max, R) f32, durs (G, T, D) int32 ->
     PolicyResult with (G, ...)-shaped fields."""
 
