@@ -131,6 +131,12 @@ class PolicyResult(NamedTuple):
     #                                     refills rather than by BF-J at
     #                                     arrival (a policy quantity: every
     #                                     engine reports the same value)
+    fit_chunks: jax.Array | None = None  # queue chunks the bfjs-mr
+    #                                     kernel's BF-S fit search scanned
+    #                                     (``kernels.bfjs_mr``); None from
+    #                                     every other engine and EXCLUDED
+    #                                     from bit-match comparisons, like
+    #                                     the streaming counters
 
 
 #: Back-compat alias (PR 1 public name).

@@ -65,7 +65,7 @@ def bfjs_mr_vmem_bytes(L: int, K: int, Qcap: int, A_max: int, R: int,
 
 def _bfjs_mr_kernel(n_ref, sizes_ref, durs_ref, cap_ref,
                     qlen_ref, occ_out_ref, ndep_ref, dropped_ref, trunc_ref,
-                    steps_ref, bfs_ref,
+                    steps_ref, bfs_ref, fit_ref,
                     dem_ref, dep_ref, occ_ref, qdem_ref, qmeta_ref, acc_ref,
                     *, L, K, R, Qcap, A_max, W, TW, EARLY_EXIT):
     w = pl.program_id(1)
@@ -79,7 +79,7 @@ def _bfjs_mr_kernel(n_ref, sizes_ref, durs_ref, cap_ref,
         row = jax.lax.broadcasted_iota(jnp.int32, (2, Qcap), 0)
         # row 0: qdur (init 1), row 1: qseq (init -1)
         qmeta_ref[...] = jnp.where(row == 0, 1, -1)
-        for i in range(6):
+        for i in range(7):
             acc_ref[i] = 0
 
     l_col = jax.lax.broadcasted_iota(jnp.int32, (L, 1), 0)
@@ -88,19 +88,22 @@ def _bfjs_mr_kernel(n_ref, sizes_ref, durs_ref, cap_ref,
     a_row = jax.lax.broadcasted_iota(jnp.int32, (1, A_max), 1)
     aa = jax.lax.broadcasted_iota(jnp.int32, (A_max, A_max), 0)
     aq = jax.lax.broadcasted_iota(jnp.int32, (A_max, Qcap), 1)
-    CH = next((c for c in (512, 256, 128) if Qcap % c == 0), Qcap)
+    CH = LANES if Qcap % LANES == 0 else Qcap
 
-    def has_fit(elig, avail):
+    def has_fit(elig, avail, n_ch):
         """(L, 1): eligible servers with room for some queued job, i.e.
-        the row-any of the (L, Qcap) fit plane, built CH queue lanes at a
-        time so the plane never lives in VMEM whole."""
+        the row-any of the (L, Qcap) fit plane, built one CH-lane chunk at
+        a time over the first ``n_ch`` chunks, which hold every queued job
+        (the slot's live-prefix bound, below); counts them in
+        ``fit_chunks``."""
         def chunk(c, acc):
             off = pl.multiple_of(c * CH, CH)
             fits = elig & (qmeta_ref[1:2, pl.ds(off, CH)] >= 0)
             for r in range(R):
                 fits = fits & (qdem_ref[r:r + 1, pl.ds(off, CH)] <= avail[r])
             return acc | fits.any(axis=1, keepdims=True).astype(jnp.int32)
-        return jax.lax.fori_loop(0, Qcap // CH, chunk,
+        acc_ref[6] = acc_ref[6] + n_ch
+        return jax.lax.fori_loop(0, n_ch, chunk,
                                  jnp.zeros((L, 1), jnp.int32)) != 0
 
     def slot_step(tt, carry):
@@ -146,6 +149,12 @@ def _bfjs_mr_kernel(n_ref, sizes_ref, durs_ref, cap_ref,
         seq0 = seq0 + n_t
         qdem_ref[...] = qdem
         qmeta_ref[...] = jnp.concatenate([qdur, qseq], axis=0)
+        # live-prefix bound: arrivals land at the lowest empty places and
+        # the work list below only empties places, so every job queued
+        # from here to the slot's end lies below the live high-water mark
+        # and the chunks past it cannot change a has_fit answer
+        hw = jnp.max(jnp.where(qseq >= 0, q_row + 1, 0))
+        n_ch = (hw + CH - 1) // CH
         landed = new_pos >= 0                                # (1, A_max)
         n_landed = landed.sum()
         # landed arrival indices, compacted ascending, + their positions
@@ -171,8 +180,8 @@ def _bfjs_mr_kernel(n_ref, sizes_ref, durs_ref, cap_ref,
             avail = [cap_ref[r] - occ_ref[r] for r in range(R)]  # (L, 1)
 
             # BF-S candidate: the fit row of server `cur`
-            cur = jnp.min(jnp.where(has_fit(freed & ~blocked, avail), l_col,
-                                    L))
+            cur = jnp.min(jnp.where(has_fit(freed & ~blocked, avail, n_ch),
+                                    l_col, L))
             any_bfs = cur < L
             fit_cur = any_bfs & (qseq >= 0)                  # (1, Qcap)
             for r in range(R):
@@ -283,7 +292,7 @@ def _bfjs_mr_kernel(n_ref, sizes_ref, durs_ref, cap_ref,
         qdem = qdem_ref[...]
         qseq = qmeta_ref[...][1:2]
         avail = [cap_ref[r] - occ_ref[r] for r in range(R)]
-        pend_bfs = has_fit(freed & (blocked == 0), avail).any()
+        pend_bfs = has_fit(freed & (blocked == 0), avail, n_ch).any()
         left = (a_row >= a_ptr) & (a_row < n_landed)
         gmask = aq == jnp.maximum(pos_list, 0).T             # (A_max, Qcap)
         seq_at = jnp.sum(jnp.where(gmask, qseq, 0), axis=1)[None, :]
@@ -308,6 +317,7 @@ def _bfjs_mr_kernel(n_ref, sizes_ref, durs_ref, cap_ref,
         acc_ref[i] = v
     dropped_ref[0, 0], trunc_ref[0, 0] = carry[2], carry[3]
     steps_ref[0, 0], bfs_ref[0, 0] = carry[4], carry[5]
+    fit_ref[0, 0] = acc_ref[6]
 
 
 @functools.partial(
@@ -328,7 +338,15 @@ def bfjs_mr_pallas(n: jax.Array, sizes: jax.Array, durs: jax.Array,
     the ``quantize.RES`` grid (``core.engine.workload.capacity_plane``),
     held in VMEM as R (L, 1) planes.  Returns per-slot (queue_len (G, T),
     occupancy (G, T, R), departures (G, T)) plus (dropped, truncated,
-    steps, bfs_placements) of shape (G,).
+    steps, bfs_placements, fit_chunks) of shape (G,).
+
+    ``fit_chunks`` counts the CH-lane queue chunks (CH = 128 where Qcap
+    allows it, else Qcap) the BF-S fit search scanned over its calls, one
+    a work step and one a slot for the saturation check.  A call scans
+    only the queue's live prefix, the chunks below the highest place
+    queued after the slot's arrivals (exact: DESIGN.md §8), so
+    ``fit_chunks / (steps + T)`` is about 1 while the queue holds under CH
+    jobs and grows to Qcap / CH as it fills.
 
     ``window`` splits the horizon into VMEM-sized chunks: the grid is
     (G, T//window) and simulation state persists in scratch across a
@@ -347,13 +365,13 @@ def bfjs_mr_pallas(n: jax.Array, sizes: jax.Array, durs: jax.Array,
     kernel = functools.partial(
         _bfjs_mr_kernel, L=L, K=K, R=R, Qcap=Qcap, A_max=A_max,
         W=work_steps, TW=TW, EARLY_EXIT=early_exit)
-    qlen, occ, ndep, dropped, trunc, steps, bfs = pl.pallas_call(
+    qlen, occ, ndep, dropped, trunc, steps, bfs, fit = pl.pallas_call(
         kernel,
         grid=(G, NW),
         out_shape=(slot_out_shape(G, T, TW, jnp.int32),
                    jax.ShapeDtypeStruct((G, NW, R, TW), jnp.float32),
                    slot_out_shape(G, T, TW, jnp.int32))
-                  + (jax.ShapeDtypeStruct((G, 1, 1), jnp.int32),) * 4,
+                  + (jax.ShapeDtypeStruct((G, 1, 1), jnp.int32),) * 5,
         in_specs=[slot_spec(TW),
                   pl.BlockSpec((1, TW, A_max * R), lambda g, w: (g, w, 0)),
                   pl.BlockSpec((1, TW, A_max), lambda g, w: (g, w, 0)),
@@ -362,13 +380,13 @@ def bfjs_mr_pallas(n: jax.Array, sizes: jax.Array, durs: jax.Array,
                    pl.BlockSpec((None, None, R, TW),
                                 lambda g, w: (g, w, 0, 0),
                                 memory_space=pltpu.SMEM),
-                   slot_spec(TW)) + (counter_spec(),) * 4,
+                   slot_spec(TW)) + (counter_spec(),) * 5,
         scratch_shapes=[pltpu.VMEM((R, L, K), jnp.int32),
                         pltpu.VMEM((L, K), jnp.int32),
                         pltpu.VMEM((R, L, 1), jnp.int32),
                         pltpu.VMEM((R, Qcap), jnp.int32),
                         pltpu.VMEM((2, Qcap), jnp.int32),
-                        pltpu.SMEM((6,), jnp.int32)],
+                        pltpu.SMEM((7,), jnp.int32)],
         compiler_params=compiler_params(
             bfjs_mr_vmem_bytes(L, K, Qcap, A_max, R, TW)),
         interpret=interpret,
@@ -376,4 +394,4 @@ def bfjs_mr_pallas(n: jax.Array, sizes: jax.Array, durs: jax.Array,
       cap.astype(jnp.int32).T[..., None])
     return (qlen.reshape(G, T), occ.transpose(0, 1, 3, 2).reshape(G, T, R),
             ndep.reshape(G, T), dropped[:, 0, 0], trunc[:, 0, 0],
-            steps[:, 0, 0], bfs[:, 0, 0])
+            steps[:, 0, 0], bfs[:, 0, 0], fit[:, 0, 0])

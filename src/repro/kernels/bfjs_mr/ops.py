@@ -44,10 +44,11 @@ def bfjs_mr_simulate(streams: SchedStreams, L: int, K: int, Qcap: int,
                            K=K, Qcap=Qcap, A_max=A_max,
                            work_steps=work_steps, capacity=capacity)
     cap = capacity_plane(capacity, L, int(streams.sizes.shape[-1]))
-    qlen, occ, ndep, dropped, trunc, steps, bfs = bfjs_mr_pallas(
+    qlen, occ, ndep, dropped, trunc, steps, bfs, fit = bfjs_mr_pallas(
         streams.n, streams.sizes, streams.durs, jnp.asarray(cap), L=L, K=K,
         Qcap=Qcap, A_max=A_max, work_steps=work_steps, window=window,
         interpret=interpret_default(), early_exit=early_exit)
     z = jnp.zeros_like(dropped)  # kernels simulate fault-free clusters
     return PolicyResult(qlen, occ, jnp.cumsum(ndep, axis=1), dropped, trunc,
-                        z, z, z, steps=steps, bfs_placements=bfs)
+                        z, z, z, steps=steps, bfs_placements=bfs,
+                        fit_chunks=fit)
