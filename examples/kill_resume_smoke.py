@@ -40,11 +40,11 @@ CHUNK = 60
 # fired — that is a failure of the harness, not a pass.
 CHILD = SETUP + """
 import os, signal, sys
-import repro.core.engine.chunked as chunked
+import repro.core.engine.streaming as streaming
 from repro.core.engine.api import run_policy_streams
 
 kill_after, ckpt_dir = int(sys.argv[1]), sys.argv[2]
-_real_save, _calls = chunked._save_step, 0
+_real_save, _calls = streaming._save_step, 0
 
 def _killing_save(*args, **kwargs):
     global _calls
@@ -53,7 +53,7 @@ def _killing_save(*args, **kwargs):
     if _calls >= kill_after:
         os.kill(os.getpid(), signal.SIGKILL)
 
-chunked._save_step = _killing_save
+streaming._save_step = _killing_save
 run_policy_streams(build(), checkpoint_dir=ckpt_dir, chunk=CHUNK, **CFG)
 sys.exit("survived past the kill point — harness broken")
 """

@@ -24,15 +24,18 @@ branch-free programs that run ON the accelerator:
     (the PR 2 loose-argument forms remain as deprecation shims);
   * ``sharding`` — the ensemble dimension G on a device mesh
     (``monte_carlo_policy(..., mesh=|devices=)``, bit-identical to the
-    single-device run; composes with ``chunked`` checkpointed sweeps);
+    single-device run; composes with checkpointed chunked sweeps);
   * ``tuning``   — the shape-keyed ``window=``/``work_steps=`` autotuner
     with its persistent, bit-match-verified tuning cache
     (``REPRO_TUNING_CACHE``);
-  * ``streaming`` — ``stream_policy`` drives chunks of any (possibly
-    infinite) arrival iterator through the stateful scan engines with
-    carried state, double-buffering host ingestion against device compute
-    (backpressure counters on ``PolicyResult``); finite traces replay
-    bit-identically to the one-shot run under any chunking;
+  * ``streaming`` — the one chunk driver: ``stream_policy`` drives chunks
+    of any (possibly infinite) arrival iterator through the stateful scan
+    engines (``chunked._STATEFUL``) with carried state, double-buffering
+    host ingestion against device compute (backpressure counters on
+    ``PolicyResult``) and checkpointing every boundary on request; finite
+    traces replay bit-identically to the one-shot run under any chunking,
+    and the checkpointed sweeps of ``run_policy_streams(chunk=)`` /
+    ``monte_carlo_policy(chunk=)`` run through it;
   * ``supervisor`` — the self-healing layer around the streaming loop
     (``stream_policy(supervisor=Supervisor(...))``): retry with jittered
     backoff on transient ingestion/staging/checkpoint failures, watchdog
@@ -54,9 +57,8 @@ from .bfjs import (BFJSResult, BFJSState, DEFAULT_MAX_REQUEUE,
                    run_bfjs_trace)
 from .bfjs_mr import (monte_carlo_bfjs_mr_workload, run_bfjs_mr_streams,
                       run_bfjs_mr_trace, run_bfjs_mr_workload)
-from .chunked import run_chunked, streams_fingerprint
 from .streaming import (iter_stream_chunks, stream_chunks_from_trace,
-                        stream_policy)
+                        stream_policy, streams_fingerprint)
 from .supervisor import (INVARIANTS, CheckpointRollbackWarning,
                          InvariantViolation, RetryPolicy, Supervisor,
                          SupervisorError, SupervisorTimeout,
@@ -83,7 +85,7 @@ __all__ = [
     "run_policy_streams", "BFJSResult", "BFJSState", "DEFAULT_MAX_REQUEUE",
     "monte_carlo_bfjs", "run_bfjs", "run_bfjs_streams", "run_bfjs_trace",
     "monte_carlo_bfjs_mr_workload", "run_bfjs_mr_streams",
-    "run_bfjs_mr_trace", "run_bfjs_mr_workload", "run_chunked",
+    "run_bfjs_mr_trace", "run_bfjs_mr_workload",
     "streams_fingerprint", "iter_stream_chunks",
     "stream_chunks_from_trace", "stream_policy",
     "INVARIANTS", "CheckpointRollbackWarning", "InvariantViolation",

@@ -176,6 +176,16 @@ def run_policy(workload, *legacy, policy: str = "bfjs",
     return get_policy(policy).run(workload, key, engine=engine, **config)
 
 
+def _check_chunked(engine: str, chunk: int | None) -> None:
+    if engine != "scan":
+        raise ValueError(
+            f'checkpointed chunked sweeps need engine="scan" (its carry is '
+            f"the entire simulation state); got engine={engine!r}")
+    if chunk is None:
+        raise ValueError("checkpoint_dir=/resume= need chunk= (the "
+                         "boundary interval, in slots)")
+
+
 def run_policy_streams(streams: SchedStreams, *, policy: str = "bfjs",
                        engine: str = "scan",
                        checkpoint_dir: str | None = None,
@@ -188,21 +198,23 @@ def run_policy_streams(streams: SchedStreams, *, policy: str = "bfjs",
     streams (``(T, A_max, R)`` sizes, e.g. ``streams_from_trace(trace,
     collapse=False)``) replay through ``policy="bfjs-mr"``.
 
-    ``chunk=``/``checkpoint_dir=`` turn the sweep crash-safe: the scan
-    engine runs in ``chunk``-slot pieces, persisting its complete carry at
-    every boundary (atomic rename) so ``resume=True`` continues a killed
-    sweep BIT-EXACTLY where it stopped (see ``core.engine.chunked``).
-    Only ``engine="scan"`` supports this — reference keeps host-side
-    state, pallas keeps VMEM-resident state; both are rejected loudly.
-    Ensemble-batched streams (leading G axis) may add ``mesh=``/
-    ``devices=`` to shard the ensemble over devices per chunk
-    (``core.engine.sharding``).
+    ``chunk=``/``checkpoint_dir=`` turn the sweep crash-safe: the streams
+    run through the chunk driver, ``stream_policy(iter_stream_chunks(
+    streams, chunk))``, which persists the scan engine's complete carry at
+    every ``chunk``-slot boundary (atomic rename) so ``resume=True``
+    continues a killed sweep BIT-EXACTLY where it stopped (see
+    ``core.engine.streaming``).  Only ``engine="scan"`` supports this —
+    reference keeps host-side state, pallas keeps VMEM-resident state;
+    both are rejected loudly.  Ensemble-batched streams (leading G axis)
+    may add ``mesh=``/``devices=`` to shard the ensemble over devices per
+    chunk (``core.engine.sharding``).  The result carries the trajectory
+    only: the driver's pipeline counters (``chunks_behind``,
+    ``host_stall_us``) stay None, as on a one-shot run.
 
     For streams that are NOT fully materialized — an unbounded arrival
-    iterator, a multi-GB trace read chunk-by-chunk — use
-    ``core.engine.stream_policy``, which threads the same carried state
-    through any chunk iterator, double-buffers host ingestion against
-    device compute, and bit-matches this function on any finite trace.
+    iterator, a multi-GB trace read chunk-by-chunk — call
+    ``core.engine.stream_policy`` on a chunk iterator directly; it
+    bit-matches this function on any finite trace.
 
     ``audit=True`` runs the runtime invariant auditor over the finished
     result (``core.engine.supervisor.audit_result`` — job conservation,
@@ -214,9 +226,7 @@ def run_policy_streams(streams: SchedStreams, *, policy: str = "bfjs",
     from .sharding import resolve_mesh
     from .tuning import apply_tuned
     mesh = resolve_mesh(mesh, devices)
-    n_res = 1 if streams.sizes.ndim == streams.durs.ndim \
-        else int(streams.sizes.shape[-1])
-    apply_tuned(policy, engine, config, n_res)
+    apply_tuned(policy, engine, config, streams.num_resources)
     audit_cfg = dict(config)
 
     def _audited(res: PolicyResult) -> PolicyResult:
@@ -226,21 +236,14 @@ def run_policy_streams(streams: SchedStreams, *, policy: str = "bfjs",
         return res
 
     if chunk is not None or checkpoint_dir is not None or resume:
-        if engine != "scan":
-            raise ValueError(
-                f'checkpointed chunked sweeps need engine="scan" (its '
-                f"carry is the entire simulation state); got "
-                f"engine={engine!r}")
-        if chunk is None:
-            raise ValueError("checkpoint_dir=/resume= need chunk= (the "
-                             "boundary interval, in slots)")
-        from .chunked import run_chunked
-        config.pop("strict", None)
-        config.pop("window", None)
-        return _audited(run_chunked(
-            streams, policy=policy, chunk=chunk,
+        _check_chunked(engine, chunk)
+        from .streaming import iter_stream_chunks, stream_policy
+        res = stream_policy(
+            iter_stream_chunks(streams, chunk), policy=policy,
             checkpoint_dir=checkpoint_dir, resume=resume,
-            stop_after_chunks=stop_after_chunks, mesh=mesh, **config))
+            stop_after_chunks=stop_after_chunks, mesh=mesh, **config)
+        return _audited(res._replace(chunks_behind=None,
+                                     host_stall_us=None))
     if mesh is not None:
         raise ValueError(
             "mesh=/devices= on run_policy_streams needs the chunked path "
@@ -271,9 +274,9 @@ def monte_carlo_policy(workload, *legacy, policy: str = "bfjs",
     to the single-device run, one G/D shard per device
     (``core.engine.sharding``; ``engine="reference"`` is host-side and
     ignores the mesh).  ``chunk=``/``checkpoint_dir=``/``resume=`` run the
-    sweep crash-safe in T-chunks (scan engine only), composing with the
-    mesh; checkpoints never pin a device count, so a sweep may resume on a
-    different mesh size.
+    sweep crash-safe in T-chunks through ``run_policy_streams``' chunk
+    driver (scan engine only), composing with the mesh; checkpoints never
+    pin a device count, so a sweep may resume on a different mesh size.
     """
     _check_engine(engine)
     if not isinstance(workload, Workload):
@@ -296,16 +299,7 @@ def monte_carlo_policy(workload, *legacy, policy: str = "bfjs",
     mesh = resolve_mesh(mesh, devices)
     apply_tuned(policy, engine, config, workload.num_resources)
     if chunk is not None or checkpoint_dir is not None or resume:
-        if engine != "scan":
-            raise ValueError(
-                f'checkpointed chunked sweeps need engine="scan" (its '
-                f"carry is the entire simulation state); got "
-                f"engine={engine!r}")
-        if chunk is None:
-            raise ValueError("checkpoint_dir=/resume= need chunk= (the "
-                             "boundary interval, in slots)")
-        config.pop("strict", None)
-        config.pop("window", None)
+        _check_chunked(engine, chunk)
         return monte_carlo_chunked(workload, keys, policy=policy,
                                    chunk=chunk, mesh=mesh,
                                    checkpoint_dir=checkpoint_dir,

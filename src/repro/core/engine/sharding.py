@@ -27,11 +27,11 @@ Engine rules:
     ``mesh=`` is accepted but ignored (the run is host-serial either way;
     parity against it is what the sharded engines are tested for).
 
-``monte_carlo_chunked`` composes the mesh with ``core.engine.chunked``:
-per-chunk carries keep the full ``(G, ...)`` shape on the host checkpoint
-(the manifest never pins a device count), so a sweep checkpointed on D
-devices resumes bit-exactly on D' — re-sharding is just the next launch's
-input placement.
+``monte_carlo_chunked`` composes the mesh with the chunk driver
+(``core.engine.streaming``): per-chunk carries keep the full ``(G, ...)``
+shape on the host checkpoint (the manifest never pins a device count), so
+a sweep checkpointed on D devices resumes bit-exactly on D' — re-sharding
+is just the next launch's input placement.
 
 On hosts without real accelerators, force a multi-device platform with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` BEFORE importing
@@ -174,21 +174,22 @@ def monte_carlo_chunked(workload, keys, *, policy: str = "bfjs",
     """Crash-safe chunked Monte-Carlo, optionally mesh-sharded.
 
     Pre-generates the whole ensemble's streams (bit-identical to the
-    per-key chains the straight Monte-Carlo path draws), then runs
-    ``core.engine.chunked.run_chunked`` with the ensemble axis vmapped —
-    and, with ``mesh=``, shard_mapped — inside each chunk.  Checkpoints
-    store the full ``(G, ...)`` carry host-side and never pin a device
-    count, so ``resume=True`` continues on any mesh whose size divides G.
+    per-key chains the straight Monte-Carlo path draws), then runs them
+    through the chunk driver (``run_policy_streams(chunk=)``) with the
+    ensemble axis vmapped — and, with ``mesh=``, shard_mapped — inside
+    each chunk.  Checkpoints store the full ``(G, ...)`` carry host-side
+    and never pin a device count, so ``resume=True`` continues on any mesh
+    whose size divides G.
     """
-    if mesh is not None:
-        _check_divides(int(keys.shape[0]), mesh)
+    from .api import run_policy_streams
+
     streams = ensemble_streams(
         workload, keys, L=config.get("L", 8), K=config.get("K", 16),
         A_max=config.get("A_max", 8), horizon=horizon,
         fault_rate=fault_rate, repair_rate=repair_rate)
     if policy == "bfjs-mr" and "capacity" not in config:
         config["capacity"] = workload.capacity
-    from .chunked import run_chunked
-    return run_chunked(streams, policy=policy, chunk=chunk, mesh=mesh,
-                       checkpoint_dir=checkpoint_dir, resume=resume,
-                       stop_after_chunks=stop_after_chunks, **config)
+    return run_policy_streams(streams, policy=policy, engine="scan",
+                              chunk=chunk, mesh=mesh,
+                              checkpoint_dir=checkpoint_dir, resume=resume,
+                              stop_after_chunks=stop_after_chunks, **config)

@@ -1,17 +1,21 @@
-"""Streaming driver: unbounded arrival iterators through the scan engines.
+"""The chunk driver: carried-state runs of the scan engines, chunk by chunk.
 
-Every other entry point replays a fixed-``T`` pre-materialized stream; the
-paper's setting (Psychas–Ghaderi 2019, Section III) is an *unbounded*
-arrival process served online.  :func:`stream_policy` iterates chunks of
-any — possibly infinite — ``SchedStreams`` iterator through the stateful
-scan engines, threading the complete carried queue/occupancy/fault state
-between chunks exactly as ``core.engine.chunked`` does, so
+Every other entry point replays a fixed-``T`` pre-materialized stream in
+one launch; the paper's setting (Psychas–Ghaderi 2019, Section III) is an
+*unbounded* arrival process served online.  :func:`stream_policy`
+iterates chunks of any — possibly infinite — ``SchedStreams`` iterator
+through the stateful scan engines (``core.engine.chunked._STATEFUL``),
+threading the complete carried queue/occupancy/fault state between
+chunks, so
 
-    **streaming replay of any finite trace is BIT-IDENTICAL to the
+    **a chunked run of any finite stream is BIT-IDENTICAL to the
     one-shot ``run_policy_streams`` run, under any chunking** —
 
 the invariant ``tests/test_streaming.py`` enforces per policy x engine x
-chunk size.  What streaming adds over ``run_chunked`` is the *pipeline*:
+chunk size.  It is the one chunk driver: the checkpointed sweeps of
+``run_policy_streams(chunk=)`` and ``monte_carlo_policy(chunk=)`` run
+through it too, fed by :func:`iter_stream_chunks`.  Around the carry it
+keeps a *pipeline*:
 
   * **Double-buffered ingestion.**  JAX dispatch is asynchronous, so while
     the device computes chunk N the host pulls chunk N+1 from the iterator
@@ -55,11 +59,20 @@ across chunks, so the request degrades loudly (GracefulDegradationWarning)
 to the bit-identical scan engine, or raises under ``strict=True``.
 ``"reference"`` keeps host-side state and is rejected.
 
-``checkpoint_dir=`` persists the carry at every chunk boundary (same
-atomic tmp-then-rename contract as chunked sweeps); ``resume=True``
-re-iterates the source, skips the chunks already executed — verifying the
-first chunk's fingerprint so a checkpoint never continues a different
-stream — and continues bit-exactly.
+Ensemble-batched streams (a leading G axis on every plane,
+``sharding.ensemble_streams``) run each chunk vmapped over the ensemble
+and, with ``mesh=``, shard_mapped so each device owns its G/D members
+(``core.engine.sharding``).
+
+``checkpoint_dir=`` persists the complete carry and the partial result at
+every chunk boundary (:mod:`repro.checkpoint.ckpt`: atomic
+tmp-then-rename, so a SIGKILL at any point leaves a complete checkpoint);
+``resume=True`` re-iterates the source, skips the chunks already executed
+and continues bit-exactly.  The manifest pins policy, trajectory mode,
+ensemble layout, fault plane, config and the first chunk's SHA-256 —
+plus, for :func:`iter_stream_chunks`, horizon, chunk length and a SHA-256
+of every stream plane — so a checkpoint never continues a different sweep
+or stream; it never pins a device count (DESIGN.md §9, §11).
 
 ``supervisor=`` (a :class:`~repro.core.engine.supervisor.Supervisor`)
 makes the loop self-healing — retry/backoff on transient ingestion,
@@ -71,10 +84,11 @@ fast path byte-for-byte unchanged (DESIGN.md §14).
 """
 from __future__ import annotations
 
+import hashlib
+import os
 import time
-import warnings
 from collections import deque
-from typing import Iterable, Iterator
+from typing import Any, Iterable, Iterator
 
 import jax
 import jax.numpy as jnp
@@ -82,50 +96,143 @@ import numpy as np
 
 from repro.checkpoint import ckpt
 
-from .chunked import (_STATEFUL, _append, _load_step, _save_step,
-                      _slice_streams, streams_fingerprint)
+from .chunked import _STATEFUL
 from .streams import PolicyResult, SchedStreams
 from .supervisor import Supervisor, SupervisorTimeout, make_auditor
 
-#: jitted ensemble (vmapped) runner pairs keyed by (policy, config items)
-#: — reused across stream_policy calls so repeated streaming runs of the
-#: same study (sweeps, benches, tests) compile once, like the module-level
-#: jits of the underlying engines.  jax.jit then re-specializes per chunk
-#: shape as usual.
+#: jitted ensemble (vmapped, and shard_mapped over a mesh) runner pairs
+#: keyed by (policy, mesh, config items) — reused across stream_policy
+#: calls so repeated chunked runs of the same study (sweeps, benches,
+#: tests) compile once, like the module-level jits of the underlying
+#: engines.  jax.jit then re-specializes per chunk shape as usual.
 _ENSEMBLE_RUNNERS: dict = {}
 
 
-def _ensemble_runners(policy: str, config: dict):
+def _ensemble_runners(policy: str, config: dict, mesh=None):
     try:
-        key = (policy, tuple(sorted(config.items())))
+        key = (policy, mesh, tuple(sorted(config.items())))
         cached = _ENSEMBLE_RUNNERS.get(key)
     except TypeError:        # unhashable config value: skip the cache
         key, cached = None, None
     if cached is not None:
         return cached
     base, cfg = _STATEFUL[policy], dict(config)
-    first_fn = jax.jit(
-        lambda s: jax.vmap(lambda x: base(x, None, cfg))(s))
-    next_fn = jax.jit(
-        lambda s, st: jax.vmap(lambda x, y: base(x, y, cfg))(s, st),
-        donate_argnums=(1,))
+
+    def first(s):
+        return jax.vmap(lambda x: base(x, None, cfg))(s)
+
+    def next_(s, st):
+        return jax.vmap(lambda x, y: base(x, y, cfg))(s, st)
+
+    if mesh is not None:
+        from jax.sharding import PartitionSpec as P
+        spec = P(mesh.axis_names[0])
+        first = jax.shard_map(first, mesh=mesh, in_specs=(spec,),
+                              out_specs=(spec, spec), check_vma=False)
+        next_ = jax.shard_map(next_, mesh=mesh, in_specs=(spec, spec),
+                              out_specs=(spec, spec), check_vma=False)
+    # the previous chunk's carry is donated: its buffers back the next
+    # chunk's state in place
+    pair = (jax.jit(first), jax.jit(next_, donate_argnums=(1,)))
     if key is not None:
-        _ENSEMBLE_RUNNERS[key] = (first_fn, next_fn)
-    return first_fn, next_fn
+        _ENSEMBLE_RUNNERS[key] = pair
+    return pair
+
+
+def streams_fingerprint(streams: SchedStreams) -> str:
+    """SHA-256 over every stream plane (dtype, shape and bytes) — the
+    resume guard that a checkpoint only ever continues its own stream."""
+    h = hashlib.sha256()
+    for name, arr in zip(streams._fields, tuple(streams)):
+        if arr is None:
+            h.update(f"{name}:none;".encode())
+        else:
+            a = np.asarray(arr)
+            h.update(f"{name}:{a.dtype}:{a.shape};".encode())
+            h.update(a.tobytes())
+    return h.hexdigest()
+
+
+def _save_step(checkpoint_dir: str, step: int, payload: Any,
+               extra: dict) -> None:
+    """One chunk-boundary save, the driver's only one (a module global so
+    crash tests can intercept the exact boundary); ``step - 1`` is the
+    chunk it follows."""
+    ckpt.save(checkpoint_dir, step, payload, extra=extra, chunk=step - 1)
+
+
+def _load_step(checkpoint_dir: str, step: int
+               ) -> tuple[tuple, PolicyResult]:
+    """Rebuild (scan state, partial result) from a boundary checkpoint.
+
+    The engine state is an anonymous tuple whose structure is
+    policy-/config-dependent, so restore by npz key layout rather than a
+    ``like`` pytree: ``state/<i>`` leaves in index order and
+    ``partial/<field>`` leaves by ``PolicyResult`` field name.
+
+    Reads go through ``ckpt.load_arrays`` — checksum-verified, so a
+    truncated or garbled file raises a typed
+    :class:`~repro.checkpoint.ckpt.CheckpointCorruptError` naming the
+    path (never a raw pickle/zip/numpy error); supervised runs catch
+    exactly that type to roll back to the last good boundary.
+    """
+    path = os.path.join(checkpoint_dir, f"step_{step:08d}", "arrays.npz")
+    data = ckpt.load_arrays(checkpoint_dir, step)
+    idxs = sorted(int(k.split("/", 1)[1]) for k in data
+                  if k.startswith("state/"))
+    if idxs != list(range(len(idxs))) or not idxs:
+        raise ckpt.CheckpointCorruptError(
+            path, f"state indices {idxs} are not a dense 0..N range")
+    state = tuple(jnp.asarray(data[f"state/{i}"]) for i in idxs)
+    # Optional fields (fault counters on unfaulted runs, the streaming
+    # backpressure/supervision counters always) are None leaves — dropped
+    # by tree_flatten at save time, so absent from the npz.
+    partial = PolicyResult(*(
+        jnp.asarray(data[f"partial/{f}"])
+        if f"partial/{f}" in data else None
+        for f in PolicyResult._fields))
+    return state, partial
 
 
 def iter_stream_chunks(streams: SchedStreams, chunk: int
                        ) -> Iterator[SchedStreams]:
     """Slice a materialized ``SchedStreams`` into contiguous time chunks —
-    the trivial chunk source (tests, benches, replaying an in-memory
-    sweep through :func:`stream_policy`).  Ensemble-batched streams
-    (leading G axis) slice along their time axis."""
+    the chunk source of checkpointed sweeps (``run_policy_streams(chunk=)``,
+    ``monte_carlo_policy(chunk=)``) and of tests and benches replaying an
+    in-memory stream through :func:`stream_policy`.  Ensemble-batched
+    streams (leading G axis) slice along their time axis."""
     if chunk <= 0:
         raise ValueError(f"chunk must be positive, got {chunk}")
-    ensemble = streams.n.ndim == 2
-    T = int(streams.n.shape[-1])
-    for lo in range(0, T, chunk):
-        yield _slice_streams(streams, lo, min(lo + chunk, T), ensemble)
+    return _StreamSlices(streams, int(chunk))
+
+
+class _StreamSlices:
+    """The iterator behind :func:`iter_stream_chunks`.  The whole stream
+    is in hand, so :meth:`manifest` can describe all of it;
+    :func:`stream_policy` pins that in its checkpoints."""
+
+    def __init__(self, streams: SchedStreams, chunk: int):
+        self.streams, self.chunk = streams, chunk
+        self.horizon = int(streams.n.shape[-1])
+        self.lo = 0
+
+    def __iter__(self):
+        return self
+
+    def __next__(self) -> SchedStreams:
+        s, lo = self.streams, self.lo
+        if lo >= self.horizon:
+            raise StopIteration
+        self.lo = hi = min(lo + self.chunk, self.horizon)
+        sl = slice(lo, hi) if s.n.ndim == 1 else (slice(None), slice(lo, hi))
+        return s._replace(n=s.n[sl], sizes=s.sizes[sl], durs=s.durs[sl],
+                          up=None if s.up is None else s.up[sl])
+
+    def manifest(self) -> dict:
+        """What a checkpoint of this source pins: horizon, chunk length
+        and the fingerprint of every stream plane."""
+        return {"horizon": self.horizon, "chunk": self.chunk,
+                "streams_sha256": streams_fingerprint(self.streams)}
 
 
 def stream_chunks_from_trace(traces: Iterable, *, chunk_slots: int,
@@ -307,6 +414,7 @@ def stream_policy(chunks: Iterable, *, policy: str = "bfjs",
                   strict: bool = False,
                   supervisor: Supervisor | None = None,
                   audit: bool = False,
+                  mesh=None,
                   **config) -> PolicyResult:
     """Run a (possibly infinite) iterator of ``SchedStreams`` chunks
     through a stateful scan engine with carried state — see the module
@@ -336,11 +444,14 @@ def stream_policy(chunks: Iterable, *, policy: str = "bfjs",
     runtime conservation laws after every chunk (jitted margins; the
     check syncs the pipeline once per chunk) and raises a typed
     ``InvariantViolation`` naming chunk and counter.
+
+    ``mesh=`` (a 1-D ``jax.sharding.Mesh``, ``sharding.resolve_mesh``)
+    shards the ensemble axis of ensemble-batched chunks over its devices.
     """
     if policy not in _STATEFUL:
         raise ValueError(
-            f"policy {policy!r} has no stateful scan engine; streaming "
-            f"supports: {', '.join(sorted(_STATEFUL))}")
+            f"policy {policy!r} has no stateful scan engine; chunked "
+            f"runs support: {', '.join(sorted(_STATEFUL))}")
     if trajectory not in ("full", "tail"):
         raise ValueError(f"trajectory must be 'full' or 'tail', "
                          f"got {trajectory!r}")
@@ -405,6 +516,13 @@ def stream_policy(chunks: Iterable, *, policy: str = "bfjs",
             from None
 
     ensemble, G, lanes, n_res = _chunk_shape(first)
+    if mesh is not None:
+        if not ensemble:
+            raise ValueError(
+                "mesh= needs ensemble-batched streams (a leading G axis on "
+                "every plane); single-run streams have nothing to shard")
+        from .sharding import _check_divides
+        _check_divides(G, mesh)
     if policy == "bfjs-mr":
         from .workload import normalize_capacity
         config["capacity"] = normalize_capacity(
@@ -412,7 +530,6 @@ def stream_policy(chunks: Iterable, *, policy: str = "bfjs",
     config.setdefault("A_max", lanes)
     from .tuning import apply_tuned
     apply_tuned(policy, "scan", config, max(n_res, 1))
-    config.pop("strict", None)
     config.pop("window", None)
 
     meta = {
@@ -423,6 +540,9 @@ def stream_policy(chunks: Iterable, *, policy: str = "bfjs",
         "first_chunk_sha256": None,  # filled below (after lifting)
         "config": {k: repr(v) for k, v in sorted(config.items())},
     }
+    describe = getattr(it, "manifest", None)
+    if checkpoint_dir is not None and describe is not None:
+        meta.update(describe())
 
     def prepare(streams_chunk: SchedStreams, index: int) -> SchedStreams:
         """Host-side chunk staging: validate shape, lift bfjs-mr planes,
@@ -442,7 +562,7 @@ def stream_policy(chunks: Iterable, *, policy: str = "bfjs",
 
     base = _STATEFUL[policy]
     if ensemble:
-        _first_fn, _next_fn = _ensemble_runners(policy, config)
+        _first_fn, _next_fn = _ensemble_runners(policy, config, mesh)
 
         def runner(streams_chunk, st):
             return _first_fn(streams_chunk) if st is None \
@@ -518,7 +638,8 @@ def stream_policy(chunks: Iterable, *, policy: str = "bfjs",
 
     staged = stage(first, 0)
     src = 0  # source index of the newest pulled chunk (quarantines count)
-    meta["first_chunk_sha256"] = streams_fingerprint(staged)
+    if checkpoint_dir is not None:
+        meta["first_chunk_sha256"] = streams_fingerprint(staged)
 
     auditor = None
     if audit:
@@ -552,8 +673,8 @@ def stream_policy(chunks: Iterable, *, policy: str = "bfjs",
             if stale:
                 raise ValueError(
                     f"checkpoint at {checkpoint_dir!r} belongs to a "
-                    f"different stream; mismatched (found, expected): "
-                    f"{stale}")
+                    f"different sweep (a different stream, policy or "
+                    f"config); mismatched (found, expected): {stale}")
             state, partial = _load_step(checkpoint_dir, latest)
             start = latest
             # skip the chunks already executed (the source re-iterates
@@ -581,16 +702,21 @@ def stream_policy(chunks: Iterable, *, policy: str = "bfjs",
                     # stream fully executed already: return the checkpoint
                     return finish(partial, 0, 0.0)
 
-    concat_axis = 1 if ensemble else 0
-    dep_off = (lambda p: p.departed[..., -1:]) if ensemble \
-        else (lambda p: p.departed[-1])
-
     def fold(part: PolicyResult | None, res: PolicyResult) -> PolicyResult:
-        if trajectory == "full":
-            return _append(part, res, axis=concat_axis)
+        """The run so far with chunk ``res`` appended (``"tail"``: in its
+        place); ``res.departed`` re-offset by the cumulative total."""
         if part is None:
             return res
-        return res._replace(departed=res.departed + dep_off(part))
+        departed = res.departed + (part.departed[..., -1:] if ensemble
+                                   else part.departed[-1])
+        if trajectory == "tail":
+            return res._replace(departed=departed)
+        def cat(a, b):
+            return jnp.concatenate([a, b], axis=1 if ensemble else 0)
+
+        return res._replace(queue_len=cat(part.queue_len, res.queue_len),
+                            occupancy=cat(part.occupancy, res.occupancy),
+                            departed=cat(part.departed, departed))
 
     executed = 0
     chunks_behind = 0
@@ -648,16 +774,16 @@ def stream_policy(chunks: Iterable, *, policy: str = "bfjs",
             # overlap for crash-safety at every boundary
             with jax.profiler.TraceAnnotation("repro.stream.checkpoint",
                                               chunk=i - 1):
-                payload = {"state": state, "partial": partial}
+                step, payload = i, {"state": state, "partial": partial}
+
+                def save():
+                    _save_step(checkpoint_dir, step, payload, meta)
+
                 if sup is None:
-                    _save_step(checkpoint_dir, i, payload, meta)
+                    save()
                 else:
-                    step = i
-                    sup.call(
-                        "checkpoint write",
-                        lambda: _save_step(checkpoint_dir, step, payload,
-                                           meta),
-                        chunk_index=step - 1)
+                    sup.call("checkpoint write", save,
+                             chunk_index=step - 1)
     # drain the tail of the pipeline so a compute watchdog covers the
     # final dispatch too
     while inflight:

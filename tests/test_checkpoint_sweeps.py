@@ -35,8 +35,9 @@ except ImportError:
     HAVE_HYPOTHESIS = False
 
 from repro.checkpoint import ckpt
-from repro.core.engine import make_streams, run_policy_streams
-from repro.core.engine import chunked
+from repro.core.engine import (iter_stream_chunks, make_streams,
+                               run_policy_streams, stream_policy)
+from repro.core.engine import streaming
 from repro.core.engine.bfjs_mr import run_bfjs_mr_streams
 
 T = 240
@@ -142,7 +143,7 @@ def _check_kill_schedule(chunk, kills, tmp_path_factory):
 _CHILD = """
 import os, signal, sys
 import jax
-import repro.core.engine.chunked as chunked
+import repro.core.engine.streaming as streaming
 from repro.core.engine import make_streams, run_policy_streams
 
 def sampler(key, n):
@@ -151,7 +152,7 @@ def sampler(key, n):
 streams = make_streams(jax.random.PRNGKey(3), 0.6, 0.5, sampler, L=4, K=3,
                        A_max=4, horizon=240, fault_rate=0.02,
                        repair_rate=0.3)
-_real, _calls = chunked._save_step, 0
+_real, _calls = streaming._save_step, 0
 
 def _killing_save(*args, **kwargs):
     global _calls
@@ -160,7 +161,7 @@ def _killing_save(*args, **kwargs):
     if _calls >= 2:
         os.kill(os.getpid(), signal.SIGKILL)
 
-chunked._save_step = _killing_save
+streaming._save_step = _killing_save
 run_policy_streams(streams, policy="bfjs", engine="scan",
                    checkpoint_dir=sys.argv[1], chunk=60, L=4, K=3, Qcap=32,
                    A_max=4)
@@ -205,13 +206,28 @@ def test_resume_refuses_mismatched_sweep(tmp_path):
     with pytest.raises(ValueError, match="different sweep"):
         run_policy_streams(other, policy="bfjs", engine="scan",
                            checkpoint_dir=d, chunk=60, resume=True, **cfg)
-    assert chunked.streams_fingerprint(other) \
-        != chunked.streams_fingerprint(streams)
+    assert streaming.streams_fingerprint(other) \
+        != streaming.streams_fingerprint(streams)
     # dropping the fault plane is a different sweep too
     with pytest.raises(ValueError, match="different sweep"):
         run_policy_streams(streams._replace(up=None), policy="bfjs",
                            engine="scan", checkpoint_dir=d, chunk=60,
                            resume=True, **cfg)
+
+
+def test_resume_refuses_a_stream_changed_after_its_first_chunk(tmp_path):
+    """The manifest pins every stream plane, not only the first chunk's:
+    a sweep whose streams differ only in the chunks not yet run is a
+    different sweep too."""
+    streams, cfg = _BFJS_STREAMS, _BFJS_CFG
+    d = str(tmp_path)
+    run_policy_streams(streams, policy="bfjs", engine="scan",
+                       checkpoint_dir=d, chunk=60, stop_after_chunks=1,
+                       **cfg)
+    late = streams._replace(durs=streams.durs.at[-1].add(1))
+    with pytest.raises(ValueError, match="different sweep"):
+        run_policy_streams(late, policy="bfjs", engine="scan",
+                           checkpoint_dir=d, chunk=60, resume=True, **cfg)
 
 
 def test_chunked_rejects_bad_usage(tmp_path):
@@ -223,15 +239,16 @@ def test_chunked_rejects_bad_usage(tmp_path):
         run_policy_streams(streams, policy="bfjs", engine="scan",
                            checkpoint_dir=str(tmp_path), **cfg)
     with pytest.raises(ValueError, match="chunk must be positive"):
-        chunked.run_chunked(streams, policy="bfjs", chunk=0, **cfg)
+        run_policy_streams(streams, policy="bfjs", engine="scan", chunk=0,
+                           **cfg)
     with pytest.raises(ValueError, match="resume=True needs"):
-        chunked.run_chunked(streams, policy="bfjs", chunk=60, resume=True,
-                            **cfg)
+        stream_policy(iter_stream_chunks(streams, 60), policy="bfjs",
+                      resume=True, **cfg)
     with pytest.raises(ValueError, match="no stateful scan engine"):
-        chunked.run_chunked(streams, policy="nope", chunk=60, **cfg)
+        stream_policy(iter_stream_chunks(streams, 60), policy="nope", **cfg)
     with pytest.raises(ValueError, match="nothing to run"):
-        chunked.run_chunked(streams, policy="bfjs", chunk=60,
-                            stop_after_chunks=0, **cfg)
+        run_policy_streams(streams, policy="bfjs", engine="scan", chunk=60,
+                           stop_after_chunks=0, **cfg)
 
 
 # ---------------------------------------------------------------------------
@@ -253,7 +270,7 @@ def test_ckpt_round_trips_engine_carry_bitexact(case, tmp_path):
     dtypes = {np.dtype(a.dtype) for a in state}
     assert {np.dtype(np.int32), np.dtype(bool)} <= dtypes, dtypes
     ckpt.save(str(tmp_path), 1, {"state": state, "partial": res})
-    state2, res2 = chunked._load_step(str(tmp_path), 1)
+    state2, res2 = streaming._load_step(str(tmp_path), 1)
     assert len(state2) == len(state)
     for i, (a, b) in enumerate(zip(state, state2)):
         assert a.dtype == b.dtype and a.shape == b.shape, (policy, i)

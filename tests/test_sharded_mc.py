@@ -88,23 +88,25 @@ def test_mesh_parity_every_policy_engine(policy, engine):
     _assert_bitmatch(res, ref, f"{policy}/{engine}: mesh != unsharded")
 
 
-def test_chunked_mesh_parity_and_resume(tmp_path):
+@pytest.mark.parametrize("policy", sorted(MATRIX))
+def test_chunked_mesh_parity_and_resume(policy, tmp_path):
     """chunk= + mesh= composes: the chunked sharded sweep equals the
     straight Monte-Carlo, and a checkpoint taken mid-sweep resumes to the
     exact same trajectory (device count re-chosen at resume time)."""
-    wl, cfg = MATRIX["bfjs"]
-    full = monte_carlo_policy(wl, _keys(), policy="bfjs", engine="scan",
+    wl, cfg = MATRIX[policy]
+    full = monte_carlo_policy(wl, _keys(), policy=policy, engine="scan",
                               **cfg)
-    chunked = monte_carlo_policy(wl, _keys(), policy="bfjs", engine="scan",
+    chunked = monte_carlo_policy(wl, _keys(), policy=policy, engine="scan",
                                  devices=1, chunk=32, **cfg)
-    _assert_bitmatch(chunked, full, "chunked+mesh != straight MC")
+    _assert_bitmatch(chunked, full, f"{policy}: chunked+mesh != straight MC")
     d = str(tmp_path)
-    monte_carlo_policy(wl, _keys(), policy="bfjs", engine="scan", devices=1,
+    monte_carlo_policy(wl, _keys(), policy=policy, engine="scan", devices=1,
                        chunk=32, checkpoint_dir=d, stop_after_chunks=1,
                        **cfg)
-    res = monte_carlo_policy(wl, _keys(), policy="bfjs", engine="scan",
+    res = monte_carlo_policy(wl, _keys(), policy=policy, engine="scan",
                              chunk=32, checkpoint_dir=d, resume=True, **cfg)
-    _assert_bitmatch(res, full, "resume (mesh -> no mesh) diverged")
+    _assert_bitmatch(res, full, f"{policy}: resume (mesh -> no mesh) "
+                                "diverged")
 
 
 # ---------------------------------------------------------------------------
@@ -131,11 +133,9 @@ def test_streams_mesh_needs_chunk():
     with pytest.raises(ValueError, match="chunk"):
         run_policy_streams(streams, policy="bfjs", engine="scan", devices=1,
                            L=4, K=6, Qcap=64, A_max=5)
-    from repro.core.engine.chunked import run_chunked
     with pytest.raises(ValueError, match="ensemble-batched"):
-        run_chunked(streams, policy="bfjs", chunk=32,
-                    mesh=resolve_mesh(devices=1), L=4, K=6, Qcap=64,
-                    A_max=5)
+        run_policy_streams(streams, policy="bfjs", engine="scan", chunk=32,
+                           devices=1, L=4, K=6, Qcap=64, A_max=5)
 
 
 # ---------------------------------------------------------------------------
