@@ -8,6 +8,15 @@ per-server ``(k_1, j*, k_{j*})`` configurations, the ``_empty`` membership
 and the subscription matrix) lives in VMEM scratch that persists across the
 sequentially-executed time windows of a member.
 
+Servers sit on lanes: the job-slot planes are ``(K, L)``, the
+subscriptions ``(2J, L)``, the configurations ``(5, L)`` rows, and every
+per-server vector of a step (residual, masks, effective configuration) is
+a ``(1, L)`` row — ``ceil(L / 128)`` vregs, where an ``(L, 1)`` column
+would take ``ceil(L / 8)``.  Reductions over a server's job slots or VQs
+run down the sublanes, reductions over servers along the lanes.  L needs
+no padding: Mosaic masks the lanes past L of a partial vreg itself, so an
+L that is not a multiple of 128 costs the tail of one vreg per row.
+
 The serve pass is the branch-free one-placement-per-step work list of
 ``repro.core.engine.vqs_bf.run_vqs_bf_streams`` — staged (i)/(ii)/(iii)
 largest-fit pops from the bucketed rings, shared max-weight renewal,
@@ -40,7 +49,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.quantize import RES
 from repro.core.partition import k_red
-from repro.kernels.common import (LANES, arrival_spec, compiler_params,
+from repro.kernels.common import (arrival_spec, compiler_params,
                                   counter_spec, resolve_windows,
                                   slot_out_shape, slot_spec, tile_bytes,
                                   to_windows)
@@ -53,22 +62,23 @@ CAP = RES
 def vqs_bf_vmem_bytes(J: int, L: int, K: int, Qcap: int, A_max: int,
                       TW: int) -> int:
     """VMEM the fused VQS-BF kernel takes on the chip: the scratch state
-    (three (L,K) planes, THREE (2J,Qcap) bucket planes — effective size,
-    duration, sequence stamp, one more than VQS for largest-fit-first FIFO
-    tie-breaking — (2,2J) counts, (5,L) per-server block, (L,2J)
+    (three (K,L) server planes, THREE (2J,Qcap) bucket planes — effective
+    size, duration, sequence stamp, one more than VQS for largest-fit-first
+    FIFO tie-breaking — (2,2J) counts, (5,L) per-server block, (2J,L)
     subscription block), the double-buffered (C, 2J) configuration table,
-    and the compiler's spills — 23 (L,128) planes of the work list's
-    per-server masks and eight (2J,Qcap) planes of the largest-fit pop.
-    All padded to (8,128) tiles.  The per-window streams live in SMEM
+    and the compiler's spills: three (K,L) planes and 16 (1,L) rows of the
+    work list's per-server masks, and three (2J,Qcap) planes of the
+    largest-fit pop.  Servers sit on lanes, so a per-server row pads to
+    ``(8, ceil(L/128)*128)`` and L costs VMEM in 128-server steps; all
+    planes padded to (8,128) tiles.  The per-window streams live in SMEM
     (``A_max`` and ``TW`` cost no VMEM).  The spill counts are fitted to
     the v5e compiler's allocation and kept honest by
     tests/test_tpu_compile.py."""
     del A_max, TW
     nvq = 2 * J
-    return (3 * tile_bytes(L, K) + 3 * tile_bytes(nvq, Qcap)
-            + tile_bytes(2, nvq) + tile_bytes(5, L) + tile_bytes(L, nvq)
-            + 2 * tile_bytes(len(k_red(J)), nvq)
-            + 23 * tile_bytes(L, LANES) + 8 * tile_bytes(nvq, Qcap))
+    return (6 * tile_bytes(K, L) + 6 * tile_bytes(nvq, Qcap)
+            + tile_bytes(2, nvq) + tile_bytes(5, L) + tile_bytes(nvq, L)
+            + 2 * tile_bytes(len(k_red(J)), nvq) + 16 * tile_bytes(1, L))
 
 
 def _vqs_bf_kernel(n_ref, sizes_ref, durs_ref, confs_ref,
@@ -83,9 +93,9 @@ def _vqs_bf_kernel(n_ref, sizes_ref, durs_ref, confs_ref,
 
     @pl.when(w == 0)
     def _init():
-        srv_ref[...] = jnp.zeros((L, K), jnp.int32)
-        dep_ref[...] = jnp.full((L, K), INF_SLOT, jnp.int32)
-        vqof_ref[...] = jnp.full((L, K), -1, jnp.int32)
+        srv_ref[...] = jnp.zeros((K, L), jnp.int32)
+        dep_ref[...] = jnp.full((K, L), INF_SLOT, jnp.int32)
+        vqof_ref[...] = jnp.full((K, L), -1, jnp.int32)
         reff_ref[...] = jnp.zeros((nvq, Qcap), jnp.int32)
         rdur_ref[...] = jnp.ones((nvq, Qcap), jnp.int32)
         rseq_ref[...] = jnp.zeros((nvq, Qcap), jnp.int32)
@@ -93,19 +103,30 @@ def _vqs_bf_kernel(n_ref, sizes_ref, durs_ref, confs_ref,
         row = jax.lax.broadcasted_iota(jnp.int32, (5, L), 0)
         # cfg_js = -1 (no active configuration); in_empty: all start empty
         cfg_ref[...] = jnp.where(row == 1, -1, jnp.where(row == 4, 1, 0))
-        want_ref[...] = jnp.zeros((L, nvq), jnp.int32)
+        want_ref[...] = jnp.zeros((nvq, L), jnp.int32)
         acc_ref[0] = 0
         acc_ref[1] = 0
         acc_ref[2] = 0
 
-    l_col = jax.lax.broadcasted_iota(jnp.int32, (L, 1), 0)
+    # servers on lanes: a per-server vector is a (1, L) row, the job slots
+    # and the VQ subscriptions of every server are (K, L) / (2J, L) planes
+    l_row = jax.lax.broadcasted_iota(jnp.int32, (1, L), 1)
+    k_col = jax.lax.broadcasted_iota(jnp.int32, (K, 1), 0)
     j_row = jax.lax.broadcasted_iota(jnp.int32, (1, nvq), 1)
+    j_col = jax.lax.broadcasted_iota(jnp.int32, (nvq, 1), 0)
     q_jq = jax.lax.broadcasted_iota(jnp.int32, (nvq, Qcap), 1)
     j_jq = jax.lax.broadcasted_iota(jnp.int32, (nvq, Qcap), 0)
-    k_row = jax.lax.broadcasted_iota(jnp.int32, (1, K), 1)
     c_col = jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0)
     c_flat = jax.lax.broadcasted_iota(jnp.int32, (C, nvq), 0)
+    diag = j_col == j_row                                      # (nvq, nvq)
     confs = confs_ref[...]
+
+    def kfree_of(rowmask, srv):
+        """Lowest empty job slot of the server ``rowmask`` selects."""
+        row_srv = jnp.sum(jnp.where(rowmask, srv, 0), axis=1,
+                          keepdims=True)                       # (K, 1)
+        kfree = jnp.min(jnp.where(row_srv == 0, k_col, K))
+        return kfree, kfree < K
 
     def slot_step(tt, carry):
         dropped, trunc, steps = carry
@@ -116,14 +137,14 @@ def _vqs_bf_kernel(n_ref, sizes_ref, durs_ref, confs_ref,
         srv = srv_ref[...]
         vqof = vqof_ref[...]
         leaving = dep == t
-        freed = leaving.any(axis=1, keepdims=True)            # (L, 1)
+        freed = leaving.any(axis=0, keepdims=True)            # (1, L)
         n_dep = leaving.sum()
         srv = jnp.where(leaving, 0, srv)
         vqof = jnp.where(leaving, -1, vqof)
         srv_ref[...] = srv
         vqof_ref[...] = vqof
         dep_ref[...] = jnp.where(leaving, INF_SLOT, dep)
-        empty_now = (srv > 0).sum(axis=1, keepdims=True) == 0  # (L, 1)
+        empty_now = (srv > 0).sum(axis=0, keepdims=True) == 0  # (1, L)
 
         # 2. arrivals: classify on the grid, push to first-empty bucket
         # slots with fresh sequence stamps (lane order == push order); each
@@ -159,25 +180,23 @@ def _vqs_bf_kernel(n_ref, sizes_ref, durs_ref, confs_ref,
             for i, v in enumerate((vq_a, pos, seq_a, eff_a, dur_a,
                                    land.astype(jnp.int32))):
                 lane_ref[i, a] = v
-            oh = j_row == vq_a                                 # (1, nvq)
-            return (qcnt + jnp.where(oh & land, 1, 0),
+            return (qcnt + jnp.where((j_row == vq_a) & land, 1, 0),
                     dropped + jnp.where(land, 0, 1),
-                    arrived | oh.astype(jnp.int32))
+                    arrived | (j_col == vq_a).astype(jnp.int32))
 
         qcnt, dropped, arrived = jax.lax.fori_loop(
             0, n_t, push,
-            (meta[0:1], dropped, jnp.zeros((1, nvq), jnp.int32)))
-        arrived = arrived != 0
+            (meta[0:1], dropped, jnp.zeros((nvq, 1), jnp.int32)))
+        arrived = arrived != 0                                 # (nvq, 1)
         meta_ref[0:1, :] = qcnt
         meta_ref[1:2, :] = jnp.where(j_row == 0, seq_ctr + A_max, meta[1:2])
 
         # 3. visit set
-        want = want_ref[...] != 0                              # (L, nvq)
-        woken = (want & arrived).any(axis=1, keepdims=True)
+        want = want_ref[...] != 0                              # (nvq, L)
+        woken = (want & arrived).any(axis=0, keepdims=True)
         want_ref[...] = (want & ~arrived).astype(jnp.int32)
-        cfgm = cfg_ref[...]
-        has_cfg0 = (cfgm[3:4].T != 0)                          # (L, 1)
-        in_empty0 = (cfgm[4:5].T != 0)
+        has_cfg0 = cfg_ref[3:4, :] != 0                        # (1, L)
+        in_empty0 = cfg_ref[4:5, :] != 0
         visit = freed | woken | (in_empty0 & (qcnt.sum() > 0))
         renew_needed = visit & (empty_now | ~has_cfg0)
 
@@ -185,7 +204,7 @@ def _vqs_bf_kernel(n_ref, sizes_ref, durs_ref, confs_ref,
         # engine's masked-select step verbatim; a step with no placer
         # advances every pending server, so the loop stops after it
         def work(wcarry):
-            # (L, 1) masks ride the loop as int32: Mosaic cannot carry
+            # (1, L) masks ride the loop as int32: Mosaic cannot carry
             # bool vectors across loop iterations
             step, touched, advanced, trunc, _ = wcarry
             touched, advanced = touched != 0, advanced != 0
@@ -195,19 +214,20 @@ def _vqs_bf_kernel(n_ref, sizes_ref, durs_ref, confs_ref,
             rseq = rseq_ref[...]
             srv = srv_ref[...]
             vqof = vqof_ref[...]
-            cfgm = cfg_ref[...]
-            cfg_k1 = (cfgm[0:1].T != 0)                        # (L, 1)
-            cfg_js = cfgm[1:2].T
-            cfg_ks = cfgm[2:3].T
-            has_cfg = (cfgm[3:4].T != 0)
-            in_empty = (cfgm[4:5].T != 0)
+            cfg_k1 = cfg_ref[0:1, :] != 0                      # (1, L)
+            cfg_js = cfg_ref[1:2, :]
+            cfg_ks = cfg_ref[2:3, :]
+            has_cfg = cfg_ref[3:4, :] != 0
+            in_empty = cfg_ref[4:5, :] != 0
             want = want_ref[...] != 0
 
             pending = visit & ~advanced
-            hx = qcnt > 0
+            hx = qcnt > 0                                      # (1, nvq)
+            hx_col = jnp.sum(jnp.where(diag, qcnt, 0), axis=1,
+                             keepdims=True) > 0                # (nvq, 1)
             occ_ring = reff > 0
-            row_min = jnp.min(jnp.where(occ_ring, reff, INF32),
-                              axis=1)[None, :]                 # (1, nvq)
+            row_min = jnp.min(jnp.where(occ_ring, reff, INF32), axis=1,
+                              keepdims=True)                   # (nvq, 1)
             glob_min = row_min.min()
 
             # shared max-weight renewal candidate (first-index argmax)
@@ -223,28 +243,28 @@ def _vqs_bf_kernel(n_ref, sizes_ref, durs_ref, confs_ref,
             ren = renew_needed & ~touched
             # bool selects as logic: Mosaic has no select of i1 vectors
             eff_k1 = (ren & r_k1) | (~ren & cfg_k1)
-            eff_js = jnp.where(ren, r_js, cfg_js)              # (L, 1)
+            eff_js = jnp.where(ren, r_js, cfg_js)              # (1, L)
             eff_ks = jnp.where(ren, r_ks, cfg_ks)
 
-            occ = srv.sum(axis=1, keepdims=True)
+            live = srv > 0                                     # (K, L)
+            occ = srv.sum(axis=0, keepdims=True)
             resid = CAP - occ
-            has_vq1 = ((vqof == 1) & (srv > 0)).any(axis=1, keepdims=True)
-            js_oh = eff_js == j_row                            # (L, nvq)
+            has_vq1 = ((vqof == 1) & live).any(axis=0, keepdims=True)
+            js_oh = eff_js == j_col                            # (nvq, L)
             js_min = jnp.min(jnp.where(js_oh, row_min, INF32),
-                             axis=1, keepdims=True)
-            js_ex = (js_oh & hx).any(axis=1, keepdims=True)
-            cnt_js = ((vqof == eff_js) & (srv > 0)).sum(axis=1,
-                                                        keepdims=True)
-            rm1 = jnp.min(jnp.where(j_row == 1, row_min, INF32))
+                             axis=0, keepdims=True)
+            js_ex = (js_oh & hx_col).any(axis=0, keepdims=True)
+            cnt_js = ((vqof == eff_js) & live).sum(axis=0, keepdims=True)
+            rm1 = jnp.min(jnp.where(j_col == 1, row_min, INF32))
 
             k1_can = eff_k1 & ~has_vq1 & (rm1 <= resid)
             js_can = (eff_js >= 0) & (cnt_js < eff_ks) & (js_min <= resid)
             any_can = glob_min <= resid
             would = pending & (k1_can | js_can | any_can)
 
-            placer = jnp.min(jnp.where(would, l_col, L))
-            tch = pending & (l_col <= placer)
-            adv = pending & (l_col < placer)
+            placer = jnp.min(jnp.where(would, l_row, L))
+            tch = pending & (l_row <= placer)
+            adv = pending & (l_row < placer)
             do_ren = tch & ren
             new_k1 = (do_ren & r_k1) | (~do_ren & cfg_k1)
             new_js = jnp.where(do_ren, r_js, cfg_js)
@@ -257,12 +277,12 @@ def _vqs_bf_kernel(n_ref, sizes_ref, durs_ref, confs_ref,
 
             sub1 = adv & eff_k1 & ~has_vq1 & ~(hx & (j_row == 1)).any()
             subj = adv & (eff_js >= 0) & (cnt_js < eff_ks) & ~js_ex
-            want = want | (sub1 & (j_row == 1)) | (subj & js_oh)
+            want = want | (sub1 & (j_col == 1)) | (subj & js_oh)
             want_ref[...] = want.astype(jnp.int32)
 
             # serve the placer: ONE staged (i)/(ii)/(iii) largest-fit pop
             any_p = placer < L
-            rowmask = l_col == placer                          # (L, 1)
+            rowmask = l_row == placer                          # (1, L)
             do1 = (rowmask & k1_can).any()
             doj = ~do1 & (rowmask & js_can).any()
             jsx_s = jnp.maximum(jnp.max(jnp.where(rowmask, eff_js, -1)), 0)
@@ -283,12 +303,8 @@ def _vqs_bf_kernel(n_ref, sizes_ref, durs_ref, confs_ref,
             dur_p = jnp.sum(jnp.where(pm, rdur, 0))
             do_place = any_p & found
 
-            row_srv = jnp.sum(jnp.where(rowmask, srv, 0),
-                              axis=0)[None, :]                 # (1, K)
-            es = row_srv == 0
-            kfree = jnp.min(jnp.where(es, k_row, K))
-            ok = kfree < K
-            lk = rowmask & (k_row == kfree) & ok & do_place    # (L, K)
+            kfree, ok = kfree_of(rowmask, srv)
+            lk = rowmask & (k_col == kfree) & ok & do_place    # (K, L)
             srv_ref[...] = jnp.where(lk, eff_p, srv)
             dep_ref[...] = jnp.where(lk, t + dur_p, dep_ref[...])
             vqof_ref[...] = jnp.where(lk, vq_p, vqof)
@@ -298,16 +314,16 @@ def _vqs_bf_kernel(n_ref, sizes_ref, durs_ref, confs_ref,
             trunc = trunc + (do_place & ~ok).astype(jnp.int32)  # K-overflow
             new_empty = new_empty & ~(rowmask & do_place)
             cfg_ref[...] = jnp.concatenate(
-                [new_k1.astype(jnp.int32).T, new_js.T, new_ks.T,
-                 new_has.astype(jnp.int32).T,
-                 new_empty.astype(jnp.int32).T], axis=0)
+                [new_k1.astype(jnp.int32), new_js, new_ks,
+                 new_has.astype(jnp.int32), new_empty.astype(jnp.int32)],
+                axis=0)
             return (step + 1, touched.astype(jnp.int32),
                     advanced.astype(jnp.int32), trunc, ~any_p)
 
-        zero_col = jnp.zeros((L, 1), jnp.int32)
+        zero_row = jnp.zeros((1, L), jnp.int32)
         n_steps, _, advanced, trunc, _ = jax.lax.while_loop(
             lambda c: (c[0] < W + 1) & jnp.logical_not(c[-1]), work,
-            (jnp.int32(0), zero_col, zero_col, trunc, jnp.bool_(False)))
+            (jnp.int32(0), zero_row, zero_row, trunc, jnp.bool_(False)))
         steps = steps + n_steps
         # bound hit with servers still unserved: slot finished lazily
         trunc = trunc + (visit & (advanced == 0)).any().astype(jnp.int32)
@@ -323,26 +339,22 @@ def _vqs_bf_kernel(n_ref, sizes_ref, durs_ref, confs_ref,
             em = (j_jq == vq_a) & (q_jq == pos_a)
             queued = (land != 0) & (jnp.sum(jnp.where(em, reff, 0)) > 0) \
                 & (jnp.sum(jnp.where(em, rseq, 0)) == seq_a)
-            resid = CAP - srv.sum(axis=1, keepdims=True)       # (L, 1)
+            resid = CAP - srv.sum(axis=0, keepdims=True)       # (1, L)
             candm = resid >= eff_a
             rbest = jnp.min(jnp.where(candm, resid, INF32))
-            s = jnp.min(jnp.where(candm & (resid == rbest), l_col, L))
+            s = jnp.min(jnp.where(candm & (resid == rbest), l_row, L))
             do = queued & (s < L)
-            rowmask = l_col == s
-            row_srv = jnp.sum(jnp.where(rowmask, srv, 0),
-                              axis=0)[None, :]
-            es = row_srv == 0
-            kfree = jnp.min(jnp.where(es, k_row, K))
-            ok = kfree < K
-            lk = rowmask & (k_row == kfree) & ok & do
+            rowmask = l_row == s
+            kfree, ok = kfree_of(rowmask, srv)
+            lk = rowmask & (k_col == kfree) & ok & do
             srv_ref[...] = jnp.where(lk, eff_a, srv)
             dep_ref[...] = jnp.where(lk, t + dur_a, dep_ref[...])
             vqof_ref[...] = jnp.where(lk, vq_a, vqof_ref[...])
             reff_ref[...] = jnp.where(em & do, 0, reff)
             meta_ref[0:1, :] = meta_ref[0:1, :] \
                 - jnp.where((j_row == vq_a) & do, 1, 0)
-            in_empty = (cfg_ref[4:5, :].T != 0) & ~(rowmask & do)
-            cfg_ref[4:5, :] = in_empty.astype(jnp.int32).T
+            in_empty = (cfg_ref[4:5, :] != 0) & ~(rowmask & do)
+            cfg_ref[4:5, :] = in_empty.astype(jnp.int32)
             return trunc + (do & ~ok).astype(jnp.int32)
 
         trunc = jax.lax.fori_loop(0, n_t, bfj, trunc)
@@ -405,15 +417,15 @@ def vqs_bf_pallas(n: jax.Array, sizes: jax.Array, durs: jax.Array,
                   pl.BlockSpec((C, nvq), lambda g, w: (0, 0))],
         out_specs=(slot_spec(TW), slot_spec(TW), slot_spec(TW),
                    counter_spec(), counter_spec(), counter_spec()),
-        scratch_shapes=[pltpu.VMEM((L, K), jnp.int32),
-                        pltpu.VMEM((L, K), jnp.int32),
-                        pltpu.VMEM((L, K), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((K, L), jnp.int32),
+                        pltpu.VMEM((K, L), jnp.int32),
+                        pltpu.VMEM((K, L), jnp.int32),
                         pltpu.VMEM((nvq, Qcap), jnp.int32),
                         pltpu.VMEM((nvq, Qcap), jnp.int32),
                         pltpu.VMEM((nvq, Qcap), jnp.int32),
                         pltpu.VMEM((2, nvq), jnp.int32),
                         pltpu.VMEM((5, L), jnp.int32),
-                        pltpu.VMEM((L, nvq), jnp.int32),
+                        pltpu.VMEM((nvq, L), jnp.int32),
                         pltpu.SMEM((3,), jnp.int32),
                         pltpu.SMEM((6, A_max), jnp.int32)],
         compiler_params=compiler_params(

@@ -4,11 +4,12 @@ Nothing runs on a chip.  Each test lowers one fused kernel at the server
 count, queue capacity and job-slot widths of ``chip_smoke.py``'s sweep
 (L=1000, K=16, Qcap=4096, window 40; the multi-resource kernel at the
 ``google2011-fleet-1000`` benchmark configuration's K=20, A_max=64 and
-two-resource per-server capacity plane) and compiles it for a v5e
-described by ``jax.experimental.topologies``, so a block shape the TPU
-tiling rule refuses, a primitive the kernel compiler cannot lower, or a
-VMEM estimate short of what the compiler allocates fails here and not on
-the chip.
+two-resource per-server capacity plane; the VQS-BF kernel also at
+L=4000, past the ~1100 servers its VMEM admitted while it kept per-server
+state in ``(L, 1)`` columns) and compiles it for a v5e described by
+``jax.experimental.topologies``, so a block shape the TPU tiling rule
+refuses, a primitive the kernel compiler cannot lower, or a VMEM estimate
+short of what the compiler allocates fails here and not on the chip.
 
 The VMEM checks pin each kernel's scoped-VMEM limit to its
 ``*_vmem_bytes`` estimate: the compile must pass (the estimate covers the
@@ -33,6 +34,8 @@ W = A_max + 4
 V5E_HBM_BYTES = 16 * 10 ** 9
 # the multi-resource kernel's job slots, arrival lanes and work bound
 MR_K, MR_A, MR_W = 20, 64, 96
+# servers of the wide VQS-BF case (its servers sit on lanes)
+WIDE_L = 4000
 
 # kernel -> (module, call, estimate, size lanes, output planes); the
 # multi-resource call also takes the (L, R) capacity plane
@@ -52,6 +55,12 @@ KERNELS = {
                    n, s, d, J=J, L=L, K=K, Qcap=Qcap, A_max=A_max,
                    work_steps=W, window=TW),
                vqs_bf_mod.vqs_bf_vmem_bytes(J, L, K, Qcap, A_max, TW), 1, 3),
+    "vqs_bf_wide": (vqs_bf_mod,
+                    lambda n, s, d: vqs_bf_mod.vqs_bf_pallas(
+                        n, s, d, J=J, L=WIDE_L, K=K, Qcap=Qcap, A_max=A_max,
+                        work_steps=W, window=TW),
+                    vqs_bf_mod.vqs_bf_vmem_bytes(J, WIDE_L, K, Qcap, A_max,
+                                                 TW), 1, 3),
     "bfjs_mr": (bfjs_mr_mod,
                 lambda n, s, d, cap: bfjs_mr_mod.bfjs_mr_pallas(
                     n, s, d, cap, L=L, K=MR_K, Qcap=Qcap, A_max=MR_A,
@@ -62,8 +71,10 @@ KERNELS = {
 
 
 def _widths(name):
-    """(K, A_max) of a kernel's compile."""
-    return (MR_K, MR_A) if name == "bfjs_mr" else (K, A_max)
+    """(L, K, A_max) of a kernel's compile."""
+    if name == "bfjs_mr":
+        return L, MR_K, MR_A
+    return (WIDE_L if name == "vqs_bf_wide" else L), K, A_max
 
 
 @pytest.fixture(scope="module")
@@ -96,11 +107,11 @@ def no_compile_cache():
 
 def _compile(name, one_chip):
     _, call, _, size_lanes, _ = KERNELS[name]
-    k, a = _widths(name)
+    l, k, a = _widths(name)
     spec = lambda shape, dt=jnp.int32: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dt, sharding=one_chip)
     sizes = (G, T, a) if size_lanes == 1 else (G, T, a, size_lanes)
-    args = [spec((G, T)), spec(sizes, jnp.float32), spec((G, T, L * k + a))]
+    args = [spec((G, T)), spec(sizes, jnp.float32), spec((G, T, l * k + a))]
     if name == "bfjs_mr":
         args.append(spec((L, R)))
     # the kernels' jitted wrappers cache their lowerings; a test that
@@ -124,9 +135,9 @@ def test_kernel_compiles_for_v5e_within_estimates(name, one_chip,
     compiled = _compile(name, one_chip)
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
-    k, a = _widths(name)
+    l, k, a = _widths(name)
     planes = ensemble_plane_bytes(G, T, stream_lanes=1 + a * size_lanes
-                                  + L * k + a, out_lanes=out_lanes)
+                                  + l * k + a, out_lanes=out_lanes)
     used = mem.argument_size_in_bytes + mem.output_size_in_bytes
     # HBM pads each plane's lane axis to 128; at these widths the
     # unpadded estimate that gates the launch is within 2% of it

@@ -166,6 +166,99 @@ def test_vqs_bf_kernel_step_counter_shows_early_exit():
 
 
 # ---------------------------------------------------------------------------
+# the kernel keeps its servers on lanes: parity where L spans lane tiles
+# ---------------------------------------------------------------------------
+class _CountingVQSBF(VQSBF):
+    """The event-driven VQS-BF, counting the placements its serve pass
+    makes (the work list's; the closing BF-J pass is not counted)."""
+
+    def bind(self, *args, **kwargs):
+        super().bind(*args, **kwargs)
+        self.serve_placements = 0
+        self._serving = False
+
+    def _serve(self, t, server):
+        self._serving = True
+        super()._serve(t, server)
+        self._serving = False
+
+    def _place(self, t, server, job):
+        self.serve_placements += self._serving
+        super()._place(t, server, job)
+
+
+@pytest.fixture(scope="module", params=[12, 200], ids=lambda L: f"L{L}")
+def lane_tile_run(request):
+    """One trace near the knee through the event-driven oracle, the scan
+    engine and the kernel (interpret mode).  L = 200 spans two 128-lane
+    tiles and is not a multiple of 128; sizes on a 1/8 grid make equal
+    residuals common, so the BF-J pass's lowest-index tie-break decides
+    between servers on both sides of the tile boundary.  The oracle also
+    counts those decisions and its serve-pass placements."""
+    from repro.core.cluster_state import Cluster
+
+    L, T, J, K, Qcap = request.param, 300, 4, 16, 512
+    # just below the knee: 1.8 jobs of mean size 1/2 offered a server,
+    # mean duration 30 slots (the queue grows from ~1.9)
+    slots, sizes, durs = _random_trace(L, T, int(1.8 * L / 30 * T), grid=8)
+    crossings = []
+    tightest = Cluster.tightest_feasible
+
+    def spy(cl, size):
+        s = tightest(cl, size)
+        if s >= 0:
+            tied = cl._by_resid[int(cl.residual[s])]
+            crossings.append(min(tied) < 128 <= max(tied))
+        return s
+
+    oracle = _CountingVQSBF(J=J)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(Cluster, "tightest_feasible", spy)
+        ref = simulate_trace(oracle, L=L, arrival_slots=slots, sizes=sizes,
+                             durations=durs, horizon=T, seed=0,
+                             record_every=1)
+    st = streams_from_trace(slots, sizes, durs, horizon=T)
+    kw = dict(J=J, L=L, K=K, Qcap=Qcap, A_max=int(st.sizes.shape[1]),
+              work_steps=WORK)
+    scn = run_vqs_bf_streams(st, **kw)
+    krn = [np.asarray(x[0]) for x in vqs_bf_pallas(
+        st.n[None], st.sizes[None], st.durs[None], interpret=True, **kw)]
+    return dict(L=L, T=T, ref=ref, oracle=oracle, crossings=crossings,
+                scan=scn, kernel=krn)
+
+
+def test_vqs_bf_kernel_bitmatches_scan_across_lane_tiles(lane_tile_run):
+    """Kernel == scan == oracle, slot for slot, at an L that is not a
+    multiple of 128 — and at L = 200 ties across the 128-server boundary
+    decided placements."""
+    r = lane_tile_run
+    scn = r["scan"]
+    qlen, occ, ndep, dropped, trunc, _ = r["kernel"]
+    assert int(dropped) == int(scn.dropped) == 0
+    assert int(trunc) == int(scn.truncated) == 0
+    np.testing.assert_array_equal(qlen, np.asarray(scn.queue_len))
+    np.testing.assert_array_equal(occ, np.asarray(scn.occupancy))
+    np.testing.assert_array_equal(np.cumsum(ndep), np.asarray(scn.departed))
+    np.testing.assert_array_equal(qlen, r["ref"].queue_lens)
+    assert qlen.max() > 0                  # near the knee: a real queue
+    if r["L"] > 128:
+        assert sum(r["crossings"]) > 0
+        assert occ.max() > 128             # servers past the tile are busy
+
+
+def test_vqs_bf_kernel_steps_equal_scan_early_exit(lane_tile_run):
+    """The scan engine's early exit runs one work step a work-list
+    placement plus the closing step with no placer; the kernel runs that
+    closing step also when nothing was visited.  So the kernel's ``steps``
+    is T plus the serve-pass placements of the oracle both engines
+    bit-match."""
+    r = lane_tile_run
+    steps = int(r["kernel"][-1])
+    assert r["oracle"].serve_placements > 0
+    assert steps == r["T"] + r["oracle"].serve_placements
+
+
+# ---------------------------------------------------------------------------
 # the paper's Section VI claim: VQS throughput, BF-like delay
 # ---------------------------------------------------------------------------
 def test_vqs_bf_tail_well_below_vqs_tail_on_shared_streams():
